@@ -2,9 +2,9 @@
 
 The RAPID dispatcher monitors simulated manipulator kinematics; every
 dispatch runs an actual prefill + autoregressive action-token decode through
-the OpenVLA-style backbone (smoke scale on CPU; swap --arch and a TPU mesh
-for production).  The chunk decode is a single fused on-device ``lax.scan``
-— no per-token host syncs.
+the OpenVLA-style backbone (the smoke preset; ``--arch X --full`` serves X at
+its published widths, ``--layers N`` cuts its depth).  The chunk decode is
+a single fused on-device ``lax.scan`` — no per-token host syncs.
 
 With ``--fleet N`` the same cloud engine serves N robots through the
 continuous-batching scheduler: dispatch triggers become requests that join
@@ -50,15 +50,20 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
 from repro.data.pipeline import EpisodeTokenizer
-from repro.launch.serve import build_policy, serve_episode, serve_fleet
+from repro.launch.serve import (
+    add_model_args,
+    build_policy,
+    serve_episode,
+    serve_fleet,
+    serving_config,
+)
 from repro.models.model import Model
 
 
 def main(argv=None):
     p = argparse.ArgumentParser()
-    p.add_argument("--arch", default="openvla-7b")
+    add_model_args(p)
     p.add_argument("--task", default="pick_place",
                    choices=["pick_place", "drawer_open", "peg_insertion"])
     p.add_argument("--steps", type=int, default=300)
@@ -118,7 +123,7 @@ def main(argv=None):
                    help="dump the fleet run's metrics registry as flat JSON")
     args = p.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+    cfg = serving_config(args.arch, args.full, args.layers)
     print(f"cloud model: {cfg.name} ({cfg.num_layers}L d={cfg.d_model})")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -161,7 +166,7 @@ def main(argv=None):
         return
 
     if args.fleet:
-        from repro.launch.serve import plan_fleet_partition
+        from repro.launch.serve import fleet_executor
         from repro.obs import Observability
         from repro.partition.planner import NETWORK_PROFILES
 
@@ -174,8 +179,9 @@ def main(argv=None):
         split = []
         robot_cuts = None
         if args.partition != "none":
-            executor, _ = plan_fleet_partition(
-                model, params, args.arch, args.network, plan_2d=args.plan_2d
+            executor = fleet_executor(
+                model, params, args.arch, args.partition, args.network,
+                plan_2d=args.plan_2d,
             )
             if executor is not None:
                 split = list(range(1, args.fleet, 2))

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 DEFAULT_BLK_S = 512
 NEG_INF = -1e30
 
@@ -131,7 +129,7 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 DEFAULT_BLK_Q = 256
 DEFAULT_BLK_K = 256
 NEG_INF = -1e30
@@ -139,7 +137,7 @@ def flash_attention(
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

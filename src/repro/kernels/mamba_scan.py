@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 DEFAULT_CHUNK = 256
 DEFAULT_BLK_H = 8
 
@@ -122,7 +120,7 @@ def mamba_scan(
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((blk_h, p, n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,6 +18,7 @@ from repro.kernels import mamba_scan as _ms
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rolling_stats as _rs
 from repro.kernels import ref as _ref
+from repro.launch.sharding import active_mesh
 
 
 def _interpret() -> bool:
@@ -64,17 +65,25 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_lens, *,
                            window=0, logit_cap=0.0):
     """Ragged-batch decode over the shared page pool (serving hot path).
 
-    Compiled Pallas on TPU.  On CPU the kernel only runs in interpret mode
-    (kernel body executed in Python — far too slow for the decode hot loop),
-    so this op routes to the vectorized jnp gather-then-attend reference,
-    which mirrors the dense ``_sdpa`` math bit for bit; the Pallas kernel
-    itself stays covered by the interpret-mode parity sweeps in
-    ``tests/test_paged_attention.py``.
+    Compiled Pallas on TPU; inside a multi-device ``sharding_rules`` mesh
+    the kernel runs under ``shard_map`` over the ``data`` axis, since
+    GSPMD cannot partition a Mosaic kernel.  On CPU the kernel only runs in
+    interpret mode (kernel body executed in Python — far too slow for the
+    decode hot loop), so this op routes to the vectorized jnp
+    gather-then-attend reference, which mirrors the dense ``_sdpa`` math
+    bit for bit; the Pallas kernel itself stays covered by the
+    interpret-mode parity sweeps in ``tests/test_paged_attention.py``.
     """
 
     if _interpret():
         return _ref.paged_decode_attention_ref(
             q, k_pages, v_pages, page_table, cache_lens,
+            window=window, logit_cap=logit_cap,
+        )
+    mesh = active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return _pa.paged_decode_attention_sharded(
+            q, k_pages, v_pages, page_table, cache_lens, mesh=mesh,
             window=window, logit_cap=logit_cap,
         )
     return _pa.paged_decode_attention(

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 DEFAULT_PAGE = 128
 NEG_INF = -1e30
 
@@ -145,7 +143,7 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -169,35 +167,42 @@ def paged_decode_attention_sharded(
     mesh,
     window: int = 0,
     logit_cap: float = 0.0,
+    interpret: bool = False,
 ) -> jax.Array:
-    """shard_map-compatible dispatch: rows shard over the mesh ``data`` axis.
+    """The kernel with its rows sharded over the mesh ``data`` axis.
 
-    Each shard runs the ordinary dispatch (Pallas kernel on TPU, the
-    reference path elsewhere) over its row slice against a full view of the
-    page pools — page ids stay global, so no table translation is needed.
-    Decode attention is per-row math with no cross-row reduction, making the
-    sharded launch bit-identical to the single-device one.
+    Mosaic kernels cannot be partitioned by GSPMD, so a jitted program that
+    spans several devices must call the kernel inside a ``shard_map``.  Each
+    shard runs it over its row slice against a full view of the page pools
+    — page ids stay global, so no table translation is needed (the ``P()``
+    pool specs gather a page-sharded pool onto every device).  Decode
+    attention is per-row math with no cross-row reduction, so the sharded
+    launch computes the same rows as the single-device one.  Rows are
+    padded to a multiple of the ``data`` axis with empty (length-0) rows.
     """
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.kernels import ops as kops
+    b = q.shape[0]
+    pad = -b % mesh.shape["data"]
+    page_table = jnp.asarray(page_table, jnp.int32)
+    cache_lens = jnp.asarray(cache_lens, jnp.int32)
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        page_table = jnp.pad(page_table, ((0, pad), (0, 0)))
+        cache_lens = jnp.pad(cache_lens, (0, pad))
 
     def local(q_, kp_, vp_, pt_, lens_):
-        return kops.paged_decode_attention(
-            q_, kp_, vp_, pt_, lens_, window=window, logit_cap=logit_cap
+        return paged_decode_attention(
+            q_, kp_, vp_, pt_, lens_, window=window, logit_cap=logit_cap,
+            interpret=interpret,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("data"), P(), P(), P("data"), P("data")),
         out_specs=P("data"),
-        check_rep=False,
+        check_vma=False,
     )
-    return fn(
-        q, k_pages, v_pages,
-        jnp.asarray(page_table, jnp.int32),
-        jnp.asarray(cache_lens, jnp.int32),
-    )
+    return fn(q, k_pages, v_pages, page_table, cache_lens)[:b]

@@ -234,10 +234,8 @@ def run_combo(
         with mesh:
             lowered = fn.lower(*args)
             compiled = lowered.compile()
-    from repro.compat import cost_dict
-
     mem = compiled.memory_analysis()
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo, loop_trip=loop_trip)
     mem_bytes = 0

@@ -11,12 +11,24 @@ from __future__ import annotations
 from typing import List, Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes.
+
+    ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` refuses; the logical-rule ``shard()`` calls
+    need Auto axes so GSPMD propagates the annotated layouts.
+    """
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
@@ -33,7 +45,7 @@ def make_host_mesh(*, model: int = 1):
     m = max(1, min(model, n))
     while n % m:
         m -= 1
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return _auto_mesh((n // m, m), ("data", "model"))
 
 
 def make_test_mesh(*, data: int, model: int = 1, devices: Optional[Sequence] = None):

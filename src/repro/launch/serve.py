@@ -1,10 +1,11 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the edge-cloud co-inference loop end to end on CPU with a smoke-scale
-cloud VLA: the RAPID dispatcher monitors simulated robot kinematics; on
-dispatch, the *actual model* (prefill + decode of action tokens through the
-KV cache) produces the chunk.  On a TPU slice the same ``CloudPolicy`` wraps
-the production-mesh sharded model.
+Runs the edge-cloud co-inference loop end to end with a real cloud VLA:
+the RAPID dispatcher monitors simulated robot kinematics; on dispatch, the
+*actual model* (prefill + decode of action tokens through the KV cache)
+produces the chunk.  The model is the smoke preset of ``--arch`` unless
+``--full`` asks for its published widths (``--layers`` cuts the depth);
+``chip_smoke.py`` at the repository root drives this path on a TPU.
 
 Two serving modes:
   * ``serve_episode`` — one robot, one ``CloudPolicy``; the action chunk is
@@ -31,7 +32,7 @@ from __future__ import annotations
 
 import argparse
 import json
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -93,31 +94,36 @@ class CloudPolicy:
             )[0]
         )
         self._paged_fns = {}
+        self._logit_fns = {}
+
+    def _paged_cache(self, dcache, b: int, prompt: int):
+        """Scatter a dense prefill cache into a fresh page pool (traced)."""
+
+        from repro.runtime.kv_cache import PagedSpec
+
+        page = self.page_size
+        maxp = -(-(prompt + self.n_steps) // page)
+        spec = PagedSpec(
+            num_pages=b * maxp, page_size=page, max_pages_per_seq=maxp
+        )
+        pt = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
+        caps = np.full((b,), maxp * page, np.int32)
+        pcache = self.model.init_paged_cache(b, spec)
+        return self.model.cache_to_paged(
+            dcache, pcache, jnp.asarray(pt), jnp.asarray(caps)
+        )
 
     def _paged_chunk_for(self, b: int, prompt: int):
         """Jitted prefill -> page scatter -> paged chunk decode, per shape."""
 
-        from repro.runtime.kv_cache import PagedSpec
-
         key = (b, prompt)
         fn = self._paged_fns.get(key)
         if fn is None:
-            page = self.page_size
-            maxp = -(-(prompt + self.n_steps) // page)
-            spec = PagedSpec(
-                num_pages=b * maxp, page_size=page, max_pages_per_seq=maxp
-            )
-            pt = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
-            caps = np.full((b,), maxp * page, np.int32)
-
             def run(p, tokens):
                 logits, dcache = self.model.prefill(
                     p, {"tokens": tokens}, extra=0
                 )
-                pcache = self.model.init_paged_cache(b, spec)
-                pcache = self.model.cache_to_paged(
-                    dcache, pcache, jnp.asarray(pt), jnp.asarray(caps)
-                )
+                pcache = self._paged_cache(dcache, b, prompt)
                 return self.model.decode_chunk(
                     p, logits, pcache, self.n_steps, self.tok.action_base
                 )[0]
@@ -125,6 +131,59 @@ class CloudPolicy:
             fn = jax.jit(run)
             self._paged_fns[key] = fn
         return fn
+
+    def step_logits(self, qd: np.ndarray, tau: np.ndarray, n_steps: int,
+                    tokens: Optional[np.ndarray] = None):
+        """Prefill, then ``n_steps`` single-token decode steps -> logits.
+
+        Returns ``(logits, fed)``: float32 logits [B, n_steps + 1, V] (the
+        prefill's last-token logits, then each decode step's) and the
+        tokens fed to the decode steps [B, n_steps].  ``tokens`` teacher-
+        forces the decode, so the dense and paged policies can be compared
+        step for step on the same inputs; by default each step feeds this
+        policy's own greedy action token.  Compare logits, not sampled
+        tokens: with random weights the top logit can flip on rounding.
+        """
+
+        if not 0 < n_steps <= self.n_steps:
+            raise ValueError(f"n_steps must be in [1, {self.n_steps}]")
+        obs = np.concatenate(
+            [self.tok.encode_state(qd), self.tok.encode_state(tau)], axis=1
+        )
+        b, prompt = obs.shape
+        base = self.tok.action_base
+
+        def run(p, obs_toks, forced):
+            logits, cache = self.model.prefill(
+                p, {"tokens": obs_toks}, extra=0 if self.paged else n_steps
+            )
+            if self.paged:
+                cache = self._paged_cache(cache, b, prompt)
+
+            def step(carry, f):
+                lg, c = carry
+                if f is None:
+                    ls = lg[:, -1].at[..., :base].set(-1e9)
+                    tok = jnp.argmax(ls, axis=-1)[:, None]
+                else:
+                    tok = f[:, None]
+                lg, c = self.model.decode_step(p, tok, c)
+                return (lg, c), (lg[:, 0], tok[:, 0])
+
+            xs = None if forced is None else jnp.swapaxes(forced, 0, 1)
+            _, (lgs, toks) = jax.lax.scan(
+                step, (logits, cache), xs, length=n_steps
+            )
+            out = jnp.concatenate([logits[:, -1:], jnp.swapaxes(lgs, 0, 1)], 1)
+            return out.astype(jnp.float32), jnp.swapaxes(toks, 0, 1)
+
+        key = (b, prompt, n_steps)
+        fn = self._logit_fns.get(key)
+        if fn is None:
+            fn = self._logit_fns[key] = jax.jit(run)
+        forced = None if tokens is None else jnp.asarray(tokens, jnp.int32)
+        logits, fed = fn(self.params, jnp.asarray(obs), forced)
+        return np.asarray(logits), np.asarray(fed)
 
     def __call__(self, qd: np.ndarray, tau: np.ndarray) -> np.ndarray:
         """qd/tau [B, N] -> action chunk [B, k, N] via autoregressive decode."""
@@ -384,6 +443,9 @@ def serve_fleet(
     actions = np.zeros((t_len, n_robots, n_joints), np.float32)
     n_off = np.zeros(n_robots, np.int64)
     wait_rounds: List[int] = []
+    # harvested chunks in harvest order:
+    # (robot id, submission round, action tokens)
+    chunks: List[Tuple[int, int, np.ndarray]] = []
     in_flight = set()
     # stochastic channel: every completed offload draws a jittered latency.
     # Keys fold in (robot id, per-robot offload ordinal), so each robot's
@@ -469,6 +531,9 @@ def serve_fleet(
                 telemetry.note_boundary(window_host_ms)
                 window_host_ms = 0.0
                 prev_closes = sched.window_closes
+            chunks.extend(
+                (res.robot_id, res.submitted_round, res.tokens) for res in results
+            )
             for res in results:
                 cached[res.robot_id] = tokenizer.decode_action(
                     res.tokens
@@ -575,6 +640,10 @@ def serve_fleet(
                     count=len(results),
                 )
                 toks = np.stack([res.tokens for res in results])
+                chunks.extend(
+                    (res.robot_id, res.submitted_round, res.tokens)
+                    for res in results
+                )
                 cached[res_ids] = tokenizer.decode_action(toks).reshape(
                     len(results), chunk_len, n_joints
                 )
@@ -636,6 +705,8 @@ def serve_fleet(
         "engine_s": engine_s,
         "host_s": max(wall_s - core_s - engine_s, 0.0),
         "actions": actions,
+        "chunks": chunks,
+        "scheduler": sched,
         "service_rounds": wait_rounds,
         "offload_ms": offload_ms,
         "offload_ms_by_robot": offload_ms_by_robot,
@@ -912,6 +983,28 @@ def replan_from_telemetry(arch: str, telemetry, network: str = "wan",
     return plan, global_plan, repriced
 
 
+def fleet_executor(model: Model, params, arch: str, partition: str,
+                   network: str = "wan", plan_2d: bool = False):
+    """The split executor a mixed fleet's split robots serve through.
+
+    ``partition`` is ``"auto"`` (``plan_fleet_partition``: ``None`` where
+    the planner keeps the whole model on one side) or an integer edge
+    layer count, which serves split lanes at that cut whatever the planner
+    would pick.
+    """
+
+    if partition == "auto":
+        return plan_fleet_partition(
+            model, params, arch, network, plan_2d=plan_2d
+        )[0]
+    from repro.partition.executor import PartitionExecutor
+    from repro.partition.planner import NETWORK_PROFILES
+
+    return PartitionExecutor(
+        model, params, int(partition), channel=NETWORK_PROFILES[network]
+    )
+
+
 def build_policy(model: Model, params, tok: EpisodeTokenizer, arch: str,
                  partition: str = "none", network: str = "wan",
                  paged: bool = False, plan_2d: bool = False,
@@ -954,9 +1047,37 @@ def build_policy(model: Model, params, tok: EpisodeTokenizer, arch: str,
     return PartitionedPolicy(executor, tok), plan
 
 
+def serving_config(arch: str, full: bool = False, layers: Optional[int] = None):
+    """The config a serving entry point builds for ``arch``.
+
+    The smoke preset by default; ``full`` gives the published widths
+    (``get_config``).  ``layers`` cuts the depth and keeps every width.
+    """
+
+    cfg = get_config(arch) if full else get_smoke_config(arch)
+    if layers is not None:
+        if not 0 < layers <= cfg.num_layers:
+            raise ValueError(
+                f"layers must be in [1, {cfg.num_layers}] for {cfg.name}"
+            )
+        cfg = cfg.replace(num_layers=layers)
+    return cfg
+
+
+def add_model_args(p: argparse.ArgumentParser) -> None:
+    """The model-selection options shared by the serving CLIs."""
+
+    p.add_argument("--arch", default="openvla-7b")
+    p.add_argument("--full", action="store_true",
+                   help="serve --arch at its published widths instead of "
+                        "the smoke preset")
+    p.add_argument("--layers", type=int, default=None,
+                   help="cut the served model's depth to N layers")
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
-    p.add_argument("--arch", default="openvla-7b")
+    add_model_args(p)
     p.add_argument("--task", default="pick_place")
     p.add_argument("--steps", type=int, default=300)
     p.add_argument("--fleet", type=int, default=0,
@@ -1006,7 +1127,7 @@ def main(argv=None):
                    help="dump the metrics in Prometheus text exposition")
     args = p.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+    cfg = serving_config(args.arch, args.full, args.layers)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     tok = EpisodeTokenizer(cfg.vocab_size)
@@ -1022,8 +1143,9 @@ def main(argv=None):
         if args.partition != "none":
             # mixed fleet: every second robot serves through the planned
             # edge-cloud split; they share decode rounds with the rest
-            executor, _ = plan_fleet_partition(
-                model, params, args.arch, args.network, plan_2d=args.plan_2d
+            executor = fleet_executor(
+                model, params, args.arch, args.partition, args.network,
+                plan_2d=args.plan_2d,
             )
             if executor is not None:
                 split = list(range(1, args.fleet, 2))

@@ -99,6 +99,12 @@ def no_sharding():
         _CTX.mesh, _CTX.rules = prev
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``sharding_rules`` context, if any."""
+
+    return _CTX.mesh
+
+
 def _axis_size(mesh: Mesh, axes: Tuple[str, ...]) -> int:
     return math.prod(mesh.shape[a] for a in axes) if axes else 1
 

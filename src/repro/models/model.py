@@ -167,6 +167,19 @@ class Model:
         with abstract_init():
             return self._init_with(jax.random.PRNGKey(0), abstract=True)[1]
 
+    def shard_params(self, params, mesh):
+        """``params`` placed on ``mesh`` by their logical axes (a no-op for
+        params already placed so)."""
+
+        from repro.launch.sharding import named_sharding
+
+        return jax.tree.map(
+            lambda ax, p: jax.device_put(
+                p, named_sharding(mesh, p.shape, ax.names)
+            ),
+            self.param_logical(), params, is_leaf=is_axes,
+        )
+
     def abstract_params(self):
         from repro.models.layers import abstract_init
 

@@ -1,7 +1,7 @@
 """Calibrated latency/load model for edge-cloud co-inference.
 
-This container is CPU-only, so wall-times are *modelled*, not measured
-(DESIGN.md §2).  The model has three calibration constants fixed against the
+Wall-times here are *modelled* for the paper's edge and cloud hardware,
+not measured (DESIGN.md §2).  The model has three calibration constants fixed against the
 paper's anchor rows (Table III Edge-Only and Cloud-Only):
 
     rate_edge  [ms/GB]  — edge device time per GB of resident model executed

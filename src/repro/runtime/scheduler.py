@@ -72,12 +72,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.pipeline import EpisodeTokenizer
 from repro.launch.sharding import (
-    named_sharding,
     no_sharding,
     shard as logical_shard,
     sharding_rules,
 )
-from repro.models.layers import is_axes
 from repro.models.model import Model
 from repro.obs.clock import clock
 from repro.runtime.kv_cache import PageAllocator, PagedSpec, donating_jit
@@ -225,16 +223,7 @@ class ContinuousBatchingScheduler:
             self._prefill_fns = {}
             self._merge_fns = {}
             self._pending_admit: List[tuple] = []
-        if mesh is not None:
-            logical = model.param_logical()
-            self.params = jax.tree.map(
-                lambda ax, p: jax.device_put(
-                    p, named_sharding(mesh, p.shape, ax.names)
-                ),
-                logical, params, is_leaf=is_axes,
-            )
-        else:
-            self.params = params
+        self.params = params if mesh is None else model.shard_params(params, mesh)
         # optional Observability handle; every producer site is guarded on
         # ``self.obs is not None`` so a None handle costs nothing.  Swappable
         # between runs (the serving bench attaches a fresh one per run).
@@ -1234,6 +1223,19 @@ class ContinuousBatchingScheduler:
         if self.obs is not None:
             self._obs_window_close(w, done)
         return done
+
+    def compiled_decode_window(self):
+        """The compiled cloud decode-window program at the live row count.
+
+        For inspection (``as_text()``, ``memory_analysis()``): lowering
+        neither runs the window nor donates the live buffers.
+        """
+
+        with self._ctx():
+            fn = self._decode_for(
+                self._block_for_depth(self.n_pending), self.scan_rounds
+            )
+            return fn.lower(self.params, self._logits, self._pcache).compile()
 
     def drain(self, max_rounds: int = 10_000) -> List[ChunkResult]:
         """Run rounds until queue and batch are empty; return all results."""

@@ -194,3 +194,28 @@ def test_merge_prefill_drops_padding_rows():
     )
     assert int(merged["len"][0]) == PROMPT and int(merged["cap"][0]) == 32
     assert int(merged["len"][1]) == 0 and int(merged["cap"][1]) == 0
+
+
+@pytest.mark.parametrize("arch", ["phi-3-vision-4.2b", "h2o-danube-3-4b"])
+def test_cloud_policy_step_logits_dense_vs_paged(arch):
+    """``CloudPolicy.step_logits``: the paged policy, teacher-forced with
+    the dense policy's greedy tokens, gives the same logits at every step
+    (bit for bit on the CPU, where paged attention is the jnp oracle), and
+    the greedy tokens are the fused chunk decode's own."""
+
+    from repro.launch.serve import CloudPolicy
+
+    cfg, model, params = _stack(arch)
+    tok = EpisodeTokenizer(cfg.vocab_size)
+    rng = np.random.default_rng(3)
+    qd, tau = rng.normal(0, 0.5, (2, 2, 7)).astype(np.float32)
+    dense = CloudPolicy(model, params, tok)
+    want, fed = dense.step_logits(qd, tau, 4)
+    got, fed_p = CloudPolicy(model, params, tok, paged=True).step_logits(
+        qd, tau, 4, tokens=fed
+    )
+    assert want.shape == got.shape and want.shape[:2] == (2, 5)
+    np.testing.assert_array_equal(fed_p, fed)
+    np.testing.assert_array_equal(got, want)
+    chunk = dense(qd, tau).reshape(2, -1)
+    np.testing.assert_array_equal(chunk[:, :4], tok.decode_action(fed))

@@ -55,10 +55,8 @@ def test_costmodel_matches_xla_on_unrolled_forward(arch):
         x, _, _ = model.forward(p, tk)
         return model._logits(params, x)
 
-    from repro.compat import cost_dict
-
     compiled = jax.jit(fwd).lower(params, batch).compile()
-    xla_flops = float(cost_dict(compiled.cost_analysis()).get("flops", 0.0))
+    xla_flops = float(compiled.cost_analysis().get("flops", 0.0))
     # forward_flops counts the full masked rectangle = what _sdpa computes
     ours = forward_flops(cfg, b, s, optimized=False)
     assert xla_flops > 0

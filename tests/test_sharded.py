@@ -126,21 +126,25 @@ def test_sharded_mixed_cut_parity_bit_exact(f32_stack, mesh):
 
 
 def test_paged_decode_attention_sharded_matches(mesh):
-    # compare against the ops-layer dispatch (Pallas on TPU, reference
-    # elsewhere) — the sharded wrapper routes each shard through exactly it
-    from repro.kernels import ops
-    from repro.kernels.paged_attention import paged_decode_attention_sharded
+    # the sharded wrapper runs the same kernel over each shard's rows (7
+    # rows: one empty pad row keeps the 8-way row split even)
+    from repro.kernels.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_sharded,
+    )
 
     rng = np.random.default_rng(7)
-    b, h, kv, d, page, pool, maxp = 8, 8, 2, 64, 16, 24, 4
+    b, h, kv, d, page, pool, maxp = 7, 8, 2, 64, 16, 24, 4
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(pool, page, kv, d)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(pool, page, kv, d)), jnp.float32)
     pt = jnp.asarray(rng.integers(0, pool, (b, maxp)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, maxp * page, (b,)), jnp.int32)
 
-    want = ops.paged_decode_attention(q, kp, vp, pt, lens)
-    got = paged_decode_attention_sharded(q, kp, vp, pt, lens, mesh=mesh)
+    want = paged_decode_attention(q, kp, vp, pt, lens, interpret=True)
+    got = paged_decode_attention_sharded(
+        q, kp, vp, pt, lens, mesh=mesh, interpret=True
+    )
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
