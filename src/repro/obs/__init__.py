@@ -5,6 +5,11 @@ One subsystem owns every measurement the serving path emits:
   * ``clock()`` — the single wall-clock source (monotonic
     ``time.perf_counter``) every serving-path timer reads, so spans from
     different components land on one comparable timeline;
+  * ``Observability.span(name)`` — a host span on the profiler's clock
+    (``jax.profiler.TraceAnnotation``) whose milliseconds land in the
+    registry's ``span_ms{span=name}`` histogram and, when tracing, on the
+    recorder's ``host`` track; ``NULL_SPAN`` is its free stand-in when
+    no handle is attached;
   * ``LatencyHistogram`` — a streaming fixed-bucket log2 histogram:
     O(1) memory, O(1) observe, mergeable across shards/episodes, with
     nearest-rank quantiles whose bucket provably contains the true
@@ -22,8 +27,8 @@ One subsystem owns every measurement the serving path emits:
 The design constraint is *zero cost when disabled*: every producer takes
 an ``Observability`` handle that may be ``None``, all stamps happen at
 host-owned boundaries the serving loop already crosses (admission,
-window close, harvest), and instrumentation never adds a host↔device
-sync — pinned by a test comparing decode outputs and ``scan_windows``
+dispatch, window close, harvest), and instrumentation never adds a
+host↔device sync — pinned by a test comparing decode outputs and ``scan_windows``
 with obs on vs off.
 """
 
@@ -31,6 +36,7 @@ from repro.obs.clock import clock
 from repro.obs.histogram import LatencyHistogram
 from repro.obs.metrics import Counter, Gauge, MetricsRegistry
 from repro.obs.slo import SLOReport, build_slo_report
+from repro.obs.span import NULL_SPAN, Span
 from repro.obs.trace import TraceRecorder, validate_chrome_trace
 
 
@@ -52,12 +58,20 @@ class Observability:
     # that already hold the handle
     clock = staticmethod(clock)
 
+    def span(self, name: str, **args) -> Span:
+        """A context manager timing one host phase as ``name`` (see
+        ``repro.obs.span``); ``args`` ride on the profiler event."""
+
+        return Span(self.metrics, self.trace, name, args)
+
     def slo_report(self) -> SLOReport:
         return build_slo_report(self.metrics)
 
 
 __all__ = [
     "Observability",
+    "NULL_SPAN",
+    "Span",
     "clock",
     "LatencyHistogram",
     "MetricsRegistry",

@@ -5,8 +5,8 @@ The serving stack stamps spans only at boundaries the host already owns
 recording a trace adds no host↔device syncs.  Spans land on named
 *tracks* — one per robot (request lifetime ⊃ queue wait ⊃ decode), one
 per scheduler lane (cloud + each partition cut: decode-window spans),
-and one host-boundary track (the per-window host orchestration gap) —
-exported as Chrome-trace JSON, loadable in Perfetto (ui.perfetto.dev)
+and one ``host`` track (the window boundary's host phases, written by
+``Observability.span``) — exported as Chrome-trace JSON, loadable in Perfetto (ui.perfetto.dev)
 or ``chrome://tracing``.
 
 Timestamps are ``obs.clock()`` (monotonic ``perf_counter``) seconds,

@@ -56,6 +56,13 @@ the metrics registry (``serve.chunk_latency_ms``, ``serve.queue_wait_ms``,
 track per robot (chunk ⊃ queue ⊃ decode) and one per lane (windows).
 Every completion harvested at a boundary shares that boundary's single
 clock read, so request spans align exactly with their window's close.
+Each window boundary runs as four host spans on the profiler's clock
+(``Observability.span``): ``sched.admit`` (``_try_admit``),
+``sched.dispatch`` (the decode-window and fused split calls),
+``sched.sync`` (the host waiting on the device for the window's tokens)
+and ``sched.harvest`` (the rest of the close).  ``sched.row_tokens``
+counts every decoded row-token by what its row held (``ROW_STATES``),
+from host integers tallied at dispatch.
 """
 
 from __future__ import annotations
@@ -78,9 +85,12 @@ from repro.launch.sharding import (
 )
 from repro.models.model import Model
 from repro.obs.clock import clock
+from repro.obs.span import NULL_SPAN
 from repro.runtime.kv_cache import PageAllocator, PagedSpec, donating_jit
 
 DEFAULT_PAGE_SIZE = 16
+# what a decode row's token held, for ``sched.row_tokens{state=...}``
+ROW_STATES = ("live", "past", "idle", "cancelled")
 
 
 def _bucket(n: int) -> int:
@@ -90,6 +100,24 @@ def _bucket(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _row_tokens(groups, n_steps: int) -> Dict[str, int]:
+    """The tokens one window decodes, ``rows x n_steps`` per decode
+    program, split by what each row holds at dispatch: ``live`` (tokens
+    its chunk still owes), ``past`` (decoded after the chunk is complete)
+    and ``idle`` (no sequence, or one whose prefill is still pending).
+    ``groups`` pairs each program's dispatched sequences with its row
+    count.  Host integers only: nothing here reads the device."""
+
+    t = dict.fromkeys(ROW_STATES, 0)
+    for seqs, rows in groups:
+        for seq in seqs:
+            take = min(seq.remaining, n_steps)
+            t["live"] += take
+            t["past"] += n_steps - take
+        t["idle"] += (rows - len(seqs)) * n_steps
+    return t
 
 
 def _lane_order(key) -> Tuple[int, tuple]:
@@ -176,6 +204,7 @@ class _ScanWindow:
     lane_toks: Dict[object, object] = field(default_factory=dict)  # by lane key
     lane_seqs: Dict[object, list] = field(default_factory=dict)
     t_open: float = 0.0                  # obs.clock at dispatch
+    row_tokens: Optional[Dict[str, int]] = None  # obs on: tally at dispatch
 
 
 class ContinuousBatchingScheduler:
@@ -312,6 +341,11 @@ class ContinuousBatchingScheduler:
                 "batch", None,
             )
             self._pcache = model.init_paged_cache(self.rows, self.paged_spec)
+
+    def _span(self, name: str):
+        """``obs.span(name)``, or the shared ``NULL_SPAN`` with obs off."""
+
+        return NULL_SPAN if self.obs is None else self.obs.span(name)
 
     def _ctx(self):
         """Mesh trace/placement context (identity without a mesh)."""
@@ -808,15 +842,9 @@ class ContinuousBatchingScheduler:
         )
         pts = tuple(jnp.asarray(l._pt) for l in lanes)
         caps = tuple(jnp.asarray(l._cap) for l in lanes)
-        t0 = clock() if self.obs is not None else 0.0
         toks, new_lanes, new_pools = fn(
             ex._per_layer, ex._base, pools, lane_in, pts, caps
         )
-        if self.obs is not None:
-            # async dispatch cost of the fused window (no sync added)
-            self.obs.metrics.histogram(
-                "sched.fused_dispatch_ms", cuts="+".join(map(str, cuts))
-            ).observe((clock() - t0) * 1e3)
         self._suffix_pools = {**self._suffix_pools, **new_pools}
         for lane, nl, tk in zip(lanes, new_lanes, toks):
             lane._edge = nl["edge"]
@@ -843,68 +871,74 @@ class ContinuousBatchingScheduler:
         suffixes (any cut) and cloud-only robots compete for the same pages
         in submission order, so no kind can starve another.  A head whose
         ``earliest_round`` lies in the future holds its lane back this round
-        (deferred admissions keep their FIFO slot)."""
+        (deferred admissions keep their FIFO slot).  The whole admission is
+        the ``sched.admit`` span."""
 
-        if self._prefill_device is not None and self._pending_admit:
-            # disaggregation phase 2: last boundary's prefill-device results
-            # merge into the live pool before any new reservations, so a
-            # cancelled pending sequence's recycled pages are never touched
-            self._merge_pending()
-        new: List[_Sequence] = []
-        new_split: Dict[object, list] = {}
-        while self.allocator.num_free >= self.pages_per_req:
-            heads = []
-            if self._queue and self._queue[0].earliest_round <= self.round:
-                heads.append((self._queue[0].order, None))
-            for key, lane in self._lanes.items():
-                if lane.queue and lane.queue[0].earliest_round <= self.round:
-                    heads.append((lane.queue[0].order, key))
-            if not heads:
-                break
-            # orders are globally unique, so min() never compares lane keys
-            _, key = min(heads, key=lambda h: h[0])
-            if key is None:
-                new.append(self._reserve(self._queue.popleft()))
-            else:
-                lane = self._lanes[key]
-                new_split.setdefault(key, []).append(
-                    lane.reserve(lane.queue.popleft())
-                )
-        if self.obs is not None and (new or new_split):
-            # one clock read per admission boundary: every sequence admitted
-            # here ends its queue-wait span on the same stamp
-            t_adm = clock()
-            m = self.obs.metrics
-            admitted = new + [s for seqs in new_split.values() for s in seqs]
-            m.counter("sched.admissions").inc(len(admitted))
-            qw = m.histogram("serve.queue_wait_ms")
-            for seq in admitted:
-                seq.admit_ts = t_adm
-                qw.observe((t_adm - seq.request.submit_ts) * 1e3)
-        for key, seqs in new_split.items():
-            self._lanes[key].flush(seqs)
-        if not new:
-            return
-        if self._prefill_device is not None:
-            self._dispatch_prefill(new)
-            return
-        n = _bucket(len(new))
-        obs = np.zeros((n, self.prompt_len), np.int64)
-        pt_new = np.zeros((n, self.pages_per_req), np.int32)
-        row_idx = np.full((n,), self.rows, np.int32)  # OOB rows -> dropped
-        lens = np.zeros((n,), np.int32)
-        caps = np.zeros((n,), np.int32)
-        for i, seq in enumerate(new):
-            obs[i] = seq.request.obs
-            pt_new[i] = seq.pages
-            row_idx[i] = seq.row
-            lens[i] = self.prompt_len
-            caps[i] = self.cap_tokens
-        self._pcache, self._logits = self._admit_for(n)(
-            self.params, self._pcache, self._logits,
-            jnp.asarray(obs), jnp.asarray(pt_new), jnp.asarray(row_idx),
-            jnp.asarray(lens), jnp.asarray(caps),
-        )
+        with self._span("sched.admit") as span:
+            if self._prefill_device is not None and self._pending_admit:
+                # disaggregation phase 2: last boundary's prefill-device
+                # results merge into the live pool before any new
+                # reservations, so a cancelled pending sequence's recycled
+                # pages are never touched
+                self._merge_pending()
+            new: List[_Sequence] = []
+            new_split: Dict[object, list] = {}
+            while self.allocator.num_free >= self.pages_per_req:
+                heads = []
+                if self._queue and self._queue[0].earliest_round <= self.round:
+                    heads.append((self._queue[0].order, None))
+                for key, lane in self._lanes.items():
+                    if lane.queue and lane.queue[0].earliest_round <= self.round:
+                        heads.append((lane.queue[0].order, key))
+                if not heads:
+                    break
+                # orders are globally unique, so min() never compares lane keys
+                _, key = min(heads, key=lambda h: h[0])
+                if key is None:
+                    new.append(self._reserve(self._queue.popleft()))
+                else:
+                    lane = self._lanes[key]
+                    new_split.setdefault(key, []).append(
+                        lane.reserve(lane.queue.popleft())
+                    )
+            n = _bucket(len(new)) if new else 0
+            if span:
+                span.set(admitted=len(new) + sum(map(len, new_split.values())),
+                         padded=n)
+            if self.obs is not None and (new or new_split):
+                # one clock read per admission boundary: every sequence
+                # admitted here ends its queue-wait span on the same stamp
+                t_adm = clock()
+                m = self.obs.metrics
+                admitted = new + [s for seqs in new_split.values() for s in seqs]
+                m.counter("sched.admissions").inc(len(admitted))
+                qw = m.histogram("serve.queue_wait_ms")
+                for seq in admitted:
+                    seq.admit_ts = t_adm
+                    qw.observe((t_adm - seq.request.submit_ts) * 1e3)
+            for key, seqs in new_split.items():
+                self._lanes[key].flush(seqs)
+            if not new:
+                return
+            if self._prefill_device is not None:
+                self._dispatch_prefill(new)
+                return
+            obs = np.zeros((n, self.prompt_len), np.int64)
+            pt_new = np.zeros((n, self.pages_per_req), np.int32)
+            row_idx = np.full((n,), self.rows, np.int32)  # OOB rows -> dropped
+            lens = np.zeros((n,), np.int32)
+            caps = np.zeros((n,), np.int32)
+            for i, seq in enumerate(new):
+                obs[i] = seq.request.obs
+                pt_new[i] = seq.pages
+                row_idx[i] = seq.row
+                lens[i] = self.prompt_len
+                caps[i] = self.cap_tokens
+            self._pcache, self._logits = self._admit_for(n)(
+                self.params, self._pcache, self._logits,
+                jnp.asarray(obs), jnp.asarray(pt_new), jnp.asarray(row_idx),
+                jnp.asarray(lens), jnp.asarray(caps),
+            )
 
     def _release(self, seq: _Sequence) -> None:
         """Return pages + row; zero the row's capacity so the (still
@@ -1078,6 +1112,18 @@ class ContinuousBatchingScheduler:
         t_end = clock()
         m = self.obs.metrics
         m.histogram("sched.window_ms").observe((t_end - w.t_open) * 1e3)
+        if w.row_tokens is not None:
+            # a row cancelled mid-window decoded for nothing: its tokens,
+            # counted live or past at dispatch, move to ``cancelled``
+            t = w.row_tokens
+            for seq in w.seqs + [s for seqs in w.lane_seqs.values() for s in seqs]:
+                if seq.dead:
+                    take = min(seq.remaining, w.n_steps)
+                    t["live"] -= take
+                    t["past"] -= w.n_steps - take
+                    t["cancelled"] += w.n_steps
+            for state, n in t.items():
+                m.counter("sched.row_tokens", state=state).inc(n)
         tr = self.obs.trace
         if tr is not None:
             name = f"window {self.windows}"
@@ -1143,10 +1189,8 @@ class ContinuousBatchingScheduler:
         block = self._block_for_depth(self.n_pending)
         if self.obs is not None:
             m = self.obs.metrics
-            m.counter("sched.decode_rounds").inc(rounds)
             m.counter("sched.windows").inc()
             m.gauge("sched.queue_depth").set(self.n_pending)
-            m.gauge("sched.active_rows").set(n_cloud + n_split)
         done: List[ChunkResult] = []
         # serial (non-pipelined) lanes ping-pong through the host, so their
         # window runs to completion at dispatch and rides this call's return
@@ -1161,20 +1205,26 @@ class ContinuousBatchingScheduler:
         w = _ScanWindow(steps_left=rounds, n_steps=rounds * block)
         if self.obs is not None:
             w.t_open = clock()
-        if n_cloud:
-            w.toks, self._logits, self._pcache = self._decode_for(block, rounds)(
-                self.params, self._logits, self._pcache
-            )
-            # pending (disaggregated-prefill) rows decode into the trash
-            # page this window; they are merged — and harvested — later
-            w.seqs = [s for s in self._seqs.values() if not s.pending]
         planes = [l for l in self._lanes.values() if l.seqs and l.pipelined]
-        if planes:
-            self._split_fused_step(planes, rounds * block)
-            for lane in planes:
-                w.lane_seqs[lane.key] = list(lane.seqs.values())
-                w.lane_toks[lane.key] = lane._pending_toks
-                lane._pending_toks = None
+        with self._span("sched.dispatch") as span:
+            if n_cloud:
+                w.toks, self._logits, self._pcache = self._decode_for(
+                    block, rounds
+                )(self.params, self._logits, self._pcache)
+                # pending (disaggregated-prefill) rows decode into the trash
+                # page this window; they are merged — and harvested — later
+                w.seqs = [s for s in self._seqs.values() if not s.pending]
+            if planes:
+                self._split_fused_step(planes, w.n_steps)
+                for lane in planes:
+                    w.lane_seqs[lane.key] = list(lane.seqs.values())
+                    w.lane_toks[lane.key] = lane._pending_toks
+                    lane._pending_toks = None
+            if span:
+                groups = [(w.seqs, self.rows)] if n_cloud else []
+                groups += [(w.lane_seqs[l.key], l.rows) for l in planes]
+                w.row_tokens = _row_tokens(groups, w.n_steps)
+                span.set(rows=sum(r for _, r in groups), steps=w.n_steps)
         self._window = w
         self._window.steps_left -= 1
         if self._window.steps_left <= 0:
@@ -1189,39 +1239,45 @@ class ContinuousBatchingScheduler:
         only the first ``remaining`` tokens are taken, so the harvested
         stream is bit-identical to the per-round path.  Dead (cancelled
         mid-window) sequences release their pages here, emitting nothing.
+        The host's wait for the device is the ``sched.sync`` span, the
+        rest the ``sched.harvest`` span.
         """
 
         w, self._window = self._window, None
         self.window_closes += 1
-        done: List[ChunkResult] = []
-        if w.toks is not None:
-            toks = np.asarray(w.toks)
-            for seq in w.seqs:
-                if seq.dead:
-                    continue
-                take = min(seq.remaining, toks.shape[1])
-                seq.tokens.extend(int(t) for t in toks[seq.row, :take])
-                seq.remaining -= take
-                if seq.remaining == 0:
-                    self._release(seq)
-                    done.append(ChunkResult(
-                        robot_id=seq.robot_id,
-                        tokens=np.asarray(seq.tokens, np.int64),
-                        submitted_round=seq.request.submitted_round,
-                        admitted_round=seq.admitted_round,
-                        completed_round=self.round,
-                        kind="cloud",
-                        pool=self.pool_stats(),
-                        submitted_ts=seq.request.submit_ts,
-                        admitted_ts=seq.admit_ts,
-                    ))
-            for seq in w.seqs:
-                if seq.dead and self._seqs.get(seq.row) is seq:
-                    self._release(seq)
-        for key, seqs in w.lane_seqs.items():
-            done.extend(self._lanes[key].harvest(seqs, w.lane_toks[key], self.round))
-        if self.obs is not None:
-            self._obs_window_close(w, done)
+        with self._span("sched.sync"):
+            toks = None if w.toks is None else np.asarray(w.toks)
+            lane_toks = {key: self._lanes[key].sync(w.lane_toks[key])
+                         for key in w.lane_seqs}
+        with self._span("sched.harvest"):
+            done: List[ChunkResult] = []
+            if toks is not None:
+                for seq in w.seqs:
+                    if seq.dead:
+                        continue
+                    take = min(seq.remaining, toks.shape[1])
+                    seq.tokens.extend(int(t) for t in toks[seq.row, :take])
+                    seq.remaining -= take
+                    if seq.remaining == 0:
+                        self._release(seq)
+                        done.append(ChunkResult(
+                            robot_id=seq.robot_id,
+                            tokens=np.asarray(seq.tokens, np.int64),
+                            submitted_round=seq.request.submitted_round,
+                            admitted_round=seq.admitted_round,
+                            completed_round=self.round,
+                            kind="cloud",
+                            pool=self.pool_stats(),
+                            submitted_ts=seq.request.submit_ts,
+                            admitted_ts=seq.admit_ts,
+                        ))
+                for seq in w.seqs:
+                    if seq.dead and self._seqs.get(seq.row) is seq:
+                        self._release(seq)
+            for key, seqs in w.lane_seqs.items():
+                done.extend(self._lanes[key].harvest(seqs, lane_toks[key], self.round))
+            if self.obs is not None:
+                self._obs_window_close(w, done)
         return done
 
     def compiled_decode_window(self):
@@ -1541,17 +1597,23 @@ class _SplitLane:
                     ))
         return done
 
-    def harvest(self, seqs: List[_SplitSeq], toks, completed_round: int
-                ) -> List[ChunkResult]:
-        """Pipelined mode, window boundary: sync the fused scan's outputs,
-        take each live sequence's tokens (over-decoded tail discarded),
-        release completions and dead (mid-window-cancelled) rows."""
+    def sync(self, toks) -> np.ndarray:
+        """Pipelined mode, window boundary: read the fused scan's logits
+        and tokens back to the host.  The logits are a writable copy: the
+        next ``flush`` writes admitted rows into them."""
+
+        self._logits = np.array(self._pending_logits, np.float32)
+        self._pending_logits = None
+        return np.asarray(toks)
+
+    def harvest(self, seqs: List[_SplitSeq], toks: np.ndarray,
+                completed_round: int) -> List[ChunkResult]:
+        """Pipelined mode, after ``sync``: take each live sequence's
+        tokens (over-decoded tail discarded), release completions and dead
+        (mid-window-cancelled) rows."""
 
         sched = self.sched
         done: List[ChunkResult] = []
-        self._logits = np.asarray(self._pending_logits, np.float32)
-        self._pending_logits = None
-        toks = np.asarray(toks)
         n_steps = toks.shape[1]
         live = [s for s in seqs if not s.dead]
         if live:

@@ -318,3 +318,145 @@ def test_observability_handle():
     obs.metrics.gauge("serve.wall_s").set(2.0)
     assert obs.slo_report().goodput_chunks_s == pytest.approx(2.0)
     assert Observability(trace=False).trace is None
+
+
+# ---------------------------------------------------------------------------
+# host spans and live-row counters in the scheduler
+# ---------------------------------------------------------------------------
+
+BOUNDARY = ("sched.admit", "sched.dispatch", "sched.sync", "sched.harvest")
+
+
+@pytest.fixture(scope="module")
+def smoke_stack():
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.data.pipeline import EpisodeTokenizer
+    from repro.models.model import Model
+
+    cfg = get_smoke_config("openvla-7b")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    return model, params, EpisodeTokenizer(cfg.vocab_size)
+
+
+def _scheduler(smoke_stack, **kw):
+    from repro.runtime.scheduler import ContinuousBatchingScheduler
+
+    model, params, tok = smoke_stack
+    return ContinuousBatchingScheduler(model, params, tok, **kw)
+
+
+def _submit(sched, robots, seed):
+    rng = np.random.default_rng(seed)
+    for r in robots:
+        sched.submit(r, rng.normal(0, 0.5, (1, 7)).astype(np.float32),
+                     rng.normal(0, 0.5, (1, 7)).astype(np.float32))
+
+
+def _row_token_counts(obs):
+    from repro.runtime.scheduler import ROW_STATES
+
+    return {s: obs.metrics.get("sched.row_tokens", state=s).value
+            for s in ROW_STATES}
+
+
+def test_span_off_is_the_shared_null_and_records_nothing(smoke_stack):
+    from repro.obs import NULL_SPAN
+
+    sched = _scheduler(smoke_stack, max_slots=2, scan_rounds=2)
+    assert sched._span("sched.admit") is NULL_SPAN
+    with sched._span("sched.dispatch") as span:
+        assert not span
+        span.set(rows=2)  # a no-op on the null span
+    _submit(sched, range(2), 0)
+    assert len(sched.drain()) == 2
+    assert sched.obs is None
+    obs = Observability()
+    sched.obs = obs
+    assert sched._span("sched.admit") is not NULL_SPAN
+    assert obs.metrics.get("span_ms", span="sched.admit") is None  # not entered
+
+
+def test_span_on_times_into_registry_and_host_track():
+    obs = Observability()
+    with obs.span("sched.admit", admitted=2) as span:
+        assert span
+        span.set(padded=4)
+    h = obs.metrics.get("span_ms", span="sched.admit")
+    assert h.count == 1 and h.total >= 0.0
+    ev = [e for e in obs.trace.to_chrome()["traceEvents"] if e.get("ph") == "X"]
+    assert [(e["name"], e["args"]) for e in ev] == [
+        ("sched.admit", {"admitted": 2, "padded": 4})]
+    assert validate_chrome_trace(obs.trace.to_chrome())[1] == []
+    # without a recorder the registry alone is fed
+    quiet = Observability(trace=False)
+    with quiet.span("sched.sync"):
+        pass
+    assert quiet.metrics.get("span_ms", span="sched.sync").count == 1
+
+
+def test_boundary_spans_land_in_the_profiler_host_plane(smoke_stack, tmp_path):
+    """Under ``jax.profiler.trace`` the four boundary spans appear in the
+    xplane host plane in boundary order, as often as ``span_ms`` counts
+    them and as the recorder's ``host`` track lists them."""
+
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    obs = Observability()
+    sched = _scheduler(smoke_stack, max_slots=2, scan_rounds=2, obs=obs)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _submit(sched, range(3), 1)
+        assert len(sched.drain()) == 3
+    path = sorted(glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb"))[-1]
+    host = sorted(
+        (e.start_ns, e.name)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name.startswith("sched.")
+    )
+    names = [n for _, n in host]
+    assert sched.windows > 1
+    assert names == list(BOUNDARY) * sched.windows
+    for name in BOUNDARY:
+        assert obs.metrics.get("span_ms", span=name).count == sched.windows
+    track = [e["name"] for e in obs.trace.to_chrome()["traceEvents"]
+             if e.get("ph") == "X" and e["name"].startswith("sched.")]
+    assert track == names
+
+
+def test_row_tokens_cover_every_row_of_every_window(smoke_stack):
+    obs = Observability(trace=False)
+    sched = _scheduler(smoke_stack, max_slots=4, scan_rounds=2, obs=obs)
+    _submit(sched, range(3), 2)
+    results = sched.drain()
+    t = _row_token_counts(obs)
+    steps = sched.scan_rounds * sched.decode_block
+    assert sum(t.values()) == sched.rows * steps * sched.windows
+    # each delivered token was owed by a live chunk exactly once
+    assert t["live"] == sum(r.tokens.size for r in results) == 3 * sched.total_tokens
+    assert t["idle"] == (sched.rows - 3) * steps * sched.windows
+    assert t["cancelled"] == 0
+
+
+def test_mid_window_cancel_moves_the_row_to_cancelled(smoke_stack):
+    obs = Observability(trace=False)
+    sched = _scheduler(smoke_stack, max_slots=2, scan_rounds=4, obs=obs)
+    _submit(sched, range(2), 3)
+    sched.step()  # both admitted, the window dispatched
+    assert sched._window is not None
+    assert sched.cancel(1)  # marked dead: the window still decodes its row
+    results = sched.drain()
+    assert [r.robot_id for r in results] == [0]
+    t = _row_token_counts(obs)
+    steps = sched.scan_rounds * sched.decode_block
+    assert t["cancelled"] == steps  # the dead row's one window
+    assert t["live"] == sched.total_tokens
+    assert sum(t.values()) == sched.rows * steps * sched.windows

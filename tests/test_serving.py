@@ -328,6 +328,36 @@ def test_split_lane_shares_page_pool(f32_stack):
     assert sched.allocator.num_free == sched.allocator.num_pages
 
 
+def test_split_lane_admits_while_a_sequence_decodes(f32_stack):
+    """Continuous arrivals into a pipelined split lane: a robot arrives at
+    every window boundary while earlier ones still decode in the lane, so
+    each admission's flush writes into the logits the last harvest read
+    back.  Every chunk matches its isolated split path (f32)."""
+
+    from repro.partition.executor import PartitionExecutor, PartitionedPolicy
+
+    _, model, params, tok = f32_stack
+    ex = PartitionExecutor(model, params, cut_layer=1)
+    sched = ContinuousBatchingScheduler(
+        model, params, tok, max_slots=2, scan_rounds=2
+    )
+    sched.attach_partition(ex)
+    rng = np.random.default_rng(23)
+    reqs = [(r, *_obs(rng)) for r in range(3)]
+    results = {}
+    for r, qd, tau in reqs:
+        sched.submit(r, qd, tau, partitioned=True)
+        for _ in range(sched.scan_rounds):  # one window: admit ... harvest
+            results.update((res.robot_id, res) for res in sched.step())
+        assert sched._window is None and sched._lanes[1].seqs
+    results.update((res.robot_id, res) for res in sched.drain())
+
+    split = PartitionedPolicy(ex, tok)
+    for r, qd, tau in reqs:
+        got = tok.decode_action(results[r].tokens).reshape(8, 7)
+        np.testing.assert_array_equal(split(qd, tau)[0], got, err_msg=f"robot {r}")
+
+
 def test_hetero_cuts_share_rounds_and_match_isolated(f32_stack):
     """Acceptance: a mixed fleet with >= 2 distinct active cuts shares one
     page allocator and decode rounds, and every robot's chunk matches its
