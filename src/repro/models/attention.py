@@ -521,8 +521,10 @@ def attention_decode_step_paged(
     ).reshape(v_pool.shape)
 
     lens_eff = jnp.minimum(pos_b + 1, cap_b)
+    # the pools go in whole: a [:n_pages] slice is a copy of each pool per
+    # call, and no table names the trash page
     out = kops.paged_decode_attention(
-        q[:, 0], k_pool[:n_pages], v_pool[:n_pages], page_table, lens_eff,
+        q[:, 0], k_pool, v_pool, page_table, lens_eff,
         window=window, logit_cap=cfg.attn_logit_softcap,
     )[:, None]
     out = out.reshape(b, 1, nh * hd) @ params["wo"].astype(x.dtype)

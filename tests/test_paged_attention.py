@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import _page_index_map, paged_decode_attention
 from repro.runtime.kv_cache import OutOfPages, PageAllocator, PagedKVCache
 
 KEY = jax.random.PRNGKey(7)
@@ -17,33 +17,94 @@ KEY = jax.random.PRNGKey(7)
 # ---------------------------------------------------------------------------
 
 PAGED_CASES = [
-    # b, h, kv, d, page, pool, maxp, lens, window, cap
-    (3, 8, 2, 64, 64, 32, 6, (1, 200, 330), 0, 0.0),
-    (2, 4, 4, 32, 32, 16, 4, (128, 7), 0, 0.0),          # MHA, page-aligned len
-    (4, 16, 1, 64, 64, 40, 8, (512, 13, 256, 100), 0, 0.0),   # MQA, heavy ragged
-    (2, 8, 2, 64, 64, 16, 4, (250, 199), 96, 0.0),       # sliding window
-    (2, 6, 3, 32, 128, 8, 2, (255, 17), 0, 30.0),        # logit cap
-    (3, 8, 4, 64, 64, 24, 5, (320, 1, 77), 64, 50.0),    # window + cap
+    # b, h, kv, d, page, pool, maxp, lens, window, cap, pool dtype
+    (3, 8, 2, 64, 64, 32, 6, (1, 200, 330), 0, 0.0, jnp.float32),
+    (2, 4, 4, 32, 32, 16, 4, (128, 7), 0, 0.0, jnp.float32),   # MHA, page-aligned len
+    (4, 16, 1, 64, 64, 40, 8, (512, 13, 256, 100), 0, 0.0, jnp.float32),  # MQA, heavy ragged
+    (2, 8, 2, 64, 64, 16, 4, (250, 199), 96, 0.0, jnp.float32),  # sliding window
+    (2, 6, 3, 32, 128, 8, 2, (255, 17), 0, 30.0, jnp.float32),  # logit cap
+    (3, 8, 4, 64, 64, 24, 5, (320, 1, 77), 64, 50.0, jnp.float32),  # window + cap
+    # danube3-4b's serving step: 16 rows of up to 5 pages over an 81-page
+    # bf16 pool, idle rows among them
+    (16, 32, 8, 120, 16, 81, 5,
+     (0, 1, 16, 17, 70, 0, 33, 48, 64, 69, 5, 0, 31, 70, 2, 50), 0, 0.0, jnp.bfloat16),
+    # phi-3-vision's MHA widths
+    (4, 32, 32, 96, 16, 21, 5, (70, 0, 17, 40), 0, 0.0, jnp.bfloat16),
+    # rows of 26 and 19 live pages: more than one 16-page block each, from
+    # a pool read in place from VMEM and from one too large for that
+    (3, 8, 2, 64, 16, 121, 40, (600, 0, 300), 400, 30.0, jnp.float32),
+    (3, 8, 2, 64, 16, 300, 40, (600, 0, 300), 400, 30.0, jnp.float32),
 ]
 
 
-@pytest.mark.parametrize("b,h,kv,d,page,pool,maxp,lens,window,cap", PAGED_CASES)
-def test_paged_decode_matches_ref(b, h, kv, d, page, pool, maxp, lens, window, cap):
+def _case_id(i, case):
+    parts = [f"lens{i}" if isinstance(v, tuple) else str(v) for v in case[:-1]]
+    if case[-1] != jnp.float32:
+        parts.append(jnp.dtype(case[-1]).name)
+    return "-".join(parts)
+
+
+@pytest.mark.parametrize(
+    "b,h,kv,d,page,pool,maxp,lens,window,cap,dtype", PAGED_CASES,
+    ids=[_case_id(i, c) for i, c in enumerate(PAGED_CASES)],
+)
+def test_paged_decode_matches_ref(b, h, kv, d, page, pool, maxp, lens, window, cap, dtype):
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (b, h, d))
-    kp = jax.random.normal(ks[1], (pool, page, kv, d))
-    vp = jax.random.normal(ks[2], (pool, page, kv, d))
+    q = jax.random.normal(ks[0], (b, h, d), dtype)
+    kp = jax.random.normal(ks[1], (pool, page, kv, d), dtype)
+    vp = jax.random.normal(ks[2], (pool, page, kv, d), dtype)
     rng = np.random.default_rng(b * 100 + h)
     table = rng.permutation(pool)[: b * maxp].reshape(b, maxp).astype(np.int32)
+    # entries past a row's pages point at page 0 or at another row's live page
+    for i, n in enumerate(lens):
+        dead = np.arange(maxp) >= -(-n // page)
+        table[i, dead] = np.where(np.arange(dead.sum()) % 2, table[(i + 1) % b, 0], 0)
+    lens = jnp.asarray(lens, jnp.int32)
     out = paged_decode_attention(
-        q, kp, vp, jnp.asarray(table), jnp.asarray(lens, jnp.int32),
+        q, kp, vp, jnp.asarray(table), lens,
         window=window, logit_cap=cap, interpret=True,
     )
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
     want = ref.paged_decode_attention_ref(
-        q, kp, vp, jnp.asarray(table), jnp.asarray(lens, jnp.int32),
+        f32(q), f32(kp), f32(vp), jnp.asarray(table), lens,
         window=window, logit_cap=cap,
     )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4, rtol=1e-4)
+    out, live = np.asarray(f32(out)), np.asarray(lens) > 0
+    tol = 1e-4 if dtype == jnp.float32 else 1e-2  # bf16 output rounding
+    np.testing.assert_allclose(out[live], np.asarray(want)[live], atol=tol, rtol=tol)
+    # a row of length 0 fetches nothing and comes back as zeros
+    assert np.all(np.isfinite(out)) and not np.any(out[~live])
+
+
+@pytest.mark.parametrize("window", [0, 40])
+def test_streamed_slots_copy_only_live_pages(window):
+    """A streamed page slot's block index, step by step in grid order: a
+    live slot names its page; any other slot repeats the index it had at
+    the step before, so the pipeline copies nothing for it."""
+
+    page, ppb, maxp = 16, 2, 5
+    lens = jnp.asarray([0, 70, 17, 0, 0, 33, 1, 80], jnp.int32)
+    table = jnp.arange(lens.size * maxp, dtype=jnp.int32).reshape(-1, maxp) + 1
+    maps = [_page_index_map(j, page=page, window=window, ppb=ppb) for j in range(ppb)]
+    prev = [None] * ppb
+    copies = live_pages = 0
+    for bi in range(lens.size):
+        n = int(lens[bi])
+        first = max(n - window, 0) // page if window else 0
+        count = -(-n // page) - first
+        live_pages += count
+        for blk in range(-(-maxp // ppb)):
+            for j, index_map in enumerate(maps):
+                idx = int(index_map(bi, blk, lens, table)[0])
+                if blk * ppb + j < count:
+                    assert idx == int(table[bi, first + blk * ppb + j])
+                else:
+                    assert prev[j] is None or idx == prev[j]
+                copies += idx != prev[j]
+                prev[j] = idx
+    # every live page once, plus the first step's copy into each slot
+    # (row 0 is idle: page 0)
+    assert copies == live_pages + ppb
 
 
 def test_paged_matches_dense_decode_ref():

@@ -26,12 +26,16 @@ from repro.kernels.paged_attention import (
 )
 
 PAGE = 16
-# (heads, kv heads, head dim, window, pages per sequence)
+# (heads, kv heads, head dim, window, pages per sequence, rows on one chip)
 WIDTHS = {
-    "phi-3-vision": (32, 32, 96, 0, 8),
+    "phi-3-vision": (32, 32, 96, 0, 8, 8),
     # a sequence that spans the 4096-token sliding window, plus a page
-    "h2o-danube3": (32, 8, 120, 4096, 4096 // PAGE + 1),
+    "h2o-danube3": (32, 8, 120, 4096, 4096 // PAGE + 1, 8),
+    # the benchmark's danube3 cell: 16 rows of 70 tokens, global attention
+    "h2o-danube3-cell": (32, 8, 120, 0, 5, 16),
 }
+# the pattern bench/programs.json reads the kernel's device time by
+KERNEL_NAME = "paged_decode_attention"
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +58,7 @@ def topo():
 
 
 def _args(widths, batch, sharding_of):
-    h, kv, d, _, maxp = widths
+    h, kv, d, _, maxp, _ = widths
     pool = 4 * -(-batch * maxp // 4)  # splits evenly over 4 chips
     shapes = [
         ((batch, h, d), jnp.bfloat16, "rows"),
@@ -72,11 +76,12 @@ def _args(widths, batch, sharding_of):
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_paged_decode_attention_compiles_for_v5e(topo, arch):
     one_chip = SingleDeviceSharding(topo.devices[0])
-    window = WIDTHS[arch][3]
+    window, rows = WIDTHS[arch][3], WIDTHS[arch][5]
     compiled = paged_decode_attention.lower(
-        *_args(WIDTHS[arch], 8, lambda kind: one_chip), window=window
+        *_args(WIDTHS[arch], rows, lambda kind: one_chip), window=window
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
+    assert calls and all(KERNEL_NAME in l.split(" = ")[0] for l in calls)
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
