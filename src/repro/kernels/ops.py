@@ -62,7 +62,7 @@ def decode_attention(q, cache_k, cache_v, *, cache_len, window=0,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, cache_lens, *,
-                           window=0, logit_cap=0.0):
+                           window=0, logit_cap=0.0, layer=None):
     """Ragged-batch decode over the shared page pool (serving hot path).
 
     Compiled Pallas on TPU; inside a multi-device ``sharding_rules`` mesh
@@ -73,21 +73,26 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_lens, *,
     gather-then-attend reference, which mirrors the dense ``_sdpa`` math
     bit for bit; the Pallas kernel itself stays covered by the
     interpret-mode parity sweeps in ``tests/test_paged_attention.py``.
+
+    With ``layer``, the pools are stacked ``[L, P, page, KV, D]`` and the op
+    reads that layer's.
     """
 
+    mesh = active_mesh()
+    if layer is not None and (_interpret() or (mesh is not None and mesh.size > 1)):
+        k_pages, v_pages, layer = k_pages[layer], v_pages[layer], None
     if _interpret():
         return _ref.paged_decode_attention_ref(
             q, k_pages, v_pages, page_table, cache_lens,
             window=window, logit_cap=logit_cap,
         )
-    mesh = active_mesh()
     if mesh is not None and mesh.size > 1:
         return _pa.paged_decode_attention_sharded(
             q, k_pages, v_pages, page_table, cache_lens, mesh=mesh,
             window=window, logit_cap=logit_cap,
         )
     return _pa.paged_decode_attention(
-        q, k_pages, v_pages, page_table, cache_lens,
+        q, k_pages, v_pages, page_table, cache_lens, layer,
         window=window, logit_cap=logit_cap, interpret=False,
     )
 

@@ -32,7 +32,9 @@ Resident pools.  Pools whose tile-padded K and V together fit
 they live: the kernel takes them in VMEM (``memory_space=VMEM``; inside a
 decode step XLA keeps a layer's freshly written pool there already) and
 each row loads its blocks' pages by page id from the scalar-prefetched
-table.  No copy is made per page, so no step waits on one.  A copy the
+table.  No copy is made per page, so no step waits on one.  Of pools
+stacked over the layers (``[L, P, page, KV, D]`` with ``layer``) the
+kernel takes that layer's, sliced out of the stack.  A copy the
 kernel starts itself (``make_async_copy`` out of a pool left in HBM)
 would need a slice of the pool whose last dimension, the head (120 or
 96), is not a multiple of 128, which Mosaic refuses.
@@ -40,11 +42,15 @@ would need a slice of the pool whose last dimension, the head (120 or
 Streamed pools.  Larger pools stay in HBM.  Each page slot of a block is
 its own pipelined operand (the pool passed once per slot, for K and V)
 with a ``(1, page, KV, D)`` block whose ``index_map`` reads the page id
-from the table.  The Pallas pipeline double-buffers them across grid
+from a per-call slot table (``_slot_pages``, scalar-prefetched in the
+table's place).  The Pallas pipeline double-buffers them across grid
 steps, so the next step's pages, the next row's included, are in flight
 while a step computes.  It copies a slot only when its index changes: a
-slot with no live page names the page it already holds
-(``_page_index_map``), so it costs no copy.
+slot with no live page names the page it already holds, so it costs no
+copy.  The table is computed once per call, vectorized: an index map that
+searched back through the rows for that page would cost O(rows) scalar
+work per slot and step.  Stacked pools are streamed from that layer
+where they lie, so a decode step copies no pool out of the stack.
 
 Skipped work.  A page is *live* if it holds a token in
 ``[max(len - window, 0), len)`` (``[0, len)`` without a window).  A row
@@ -166,36 +172,36 @@ def _resident_kernel(
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _page_index_map(j: int, *, page: int, window: int, ppb: int):
-    """Pool page that page slot ``j`` of grid step (row, block) holds.
+def _slot_pages(lens, table, *, page: int, window: int, ppb: int):
+    """[B * NB * PPB] pool page that page slot ``j`` of grid step ``(row,
+    block)`` holds, at ``(row * NB + block) * PPB + j`` (NB blocks a row).
 
     A live slot names its page.  Any other slot names the page it held at
     the grid step before, so the pipeline, which copies a block only when
-    its index changes, fetches nothing for it: the row's last block that
-    had slot ``j`` live, else the nearest earlier row with such a block,
-    else page 0 (fetched once, at the first step).
+    its index changes, fetches nothing for it: the latest live page of
+    slot ``j`` in grid order, else page 0 (fetched once, at the first
+    step).  Computed once per call, vectorized, so each index map is one
+    lookup.
     """
 
-    def index_map(bi, blk, lens, table):
-        def span(r):
-            return _live_pages(lens[r], page=page, window=window)
-
-        first, count = span(bi)
-        here = table[bi, jnp.minimum(first + blk * ppb + j, table.shape[1] - 1)]
-        r = jax.lax.while_loop(
-            lambda r: (r >= 0) & (span(jnp.maximum(r, 0))[1] <= j), lambda r: r - 1, bi
-        )
-        first_r, count_r = span(jnp.maximum(r, 0))
-        last_blk = jnp.maximum(count_r - 1 - j, 0) // ppb
-        held = jnp.where(r >= 0, table[jnp.maximum(r, 0), first_r + last_blk * ppb + j], 0)
-        return jnp.where(blk * ppb + j < count, here, held), 0, 0, 0
-
-    return index_map
+    b, maxp = table.shape
+    nb = -(-maxp // ppb)
+    first, count = _live_pages(lens, page=page, window=window)
+    first = jnp.broadcast_to(first, lens.shape)
+    k = jnp.arange(nb * ppb)                       # page of the row's live span
+    live = (k[None, :] < count[:, None]).reshape(b * nb, ppb)
+    idx = jnp.minimum(first[:, None] + k[None, :], maxp - 1)
+    pages = jnp.take_along_axis(table, idx, axis=1).reshape(b * nb, ppb)
+    steps = jnp.arange(b * nb)[:, None]
+    last = jax.lax.cummax(jnp.where(live, steps, -1), axis=0)
+    held = jnp.take_along_axis(pages, jnp.maximum(last, 0), axis=0)
+    return jnp.where(last >= 0, held, 0).reshape(-1)
 
 
 def _streamed_kernel(
     lens_ref,              # scalar prefetch: [B] int32 per-seq cache length
-    table_ref,             # scalar prefetch: [B, MAXP] int32 page table
+    slots_ref,             # scalar prefetch: [B * NB * PPB] int32 (_slot_pages)
+    layer_ref,             # scalar prefetch: [1] int32 layer of the stacked pools
     q_ref,                 # [1, H, D]
     *refs,                 # PPB K pages, PPB V pages [1, page, KV, D]; o_ref
                            # [1, H, D]; m, l, acc scratch [H,1], [H,1], [H,D]
@@ -233,46 +239,68 @@ def _streamed_kernel(
         o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
+def pool_streamed(k_pages) -> bool:
+    """Whether pools shaped like ``k_pages`` (``[..., P, page, KV, D]``, K
+    and V alike) are too large for the kernel to read in place from VMEM,
+    so that their pages are streamed."""
+
+    pool, page, kv, d = k_pages.shape[-4:]
+    return 2 * pool * _page_vmem_bytes(page, kv, d, k_pages.dtype) > VMEM_POOL_BUDGET
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("window", "logit_cap", "interpret"),
 )
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D]
-    k_pages: jax.Array,     # [P, page, KV, D]
+    k_pages: jax.Array,     # [P, page, KV, D], or [L, P, page, KV, D] with ``layer``
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, MAXP] int32
     cache_lens: jax.Array,  # [B] int32
+    layer: jax.Array | None = None,  # int32 scalar: the layer of stacked pools
     *,
     window: int = 0,
     logit_cap: float = 0.0,
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
-    pool, page, kv, _ = k_pages.shape
+    pool, page, kv, _ = k_pages.shape[-4:]
     maxp = page_table.shape[1]
     ppb = pages_per_block(maxp, page, kv, d, k_pages.dtype)
-    pool_bytes = 2 * pool * _page_vmem_bytes(page, kv, d, k_pages.dtype)
     kw = dict(
         page=page, ppb=ppb, window=window, groups=h // kv, sm_scale=d**-0.5,
         logit_cap=logit_cap,
     )
     row_spec = pl.BlockSpec((1, h, d), lambda bi, *_: (bi, 0, 0))
-    if pool_bytes <= VMEM_POOL_BUDGET:
+    lens = jnp.asarray(cache_lens, jnp.int32)
+    if not pool_streamed(k_pages):
+        if layer is not None:  # the layer's pool, sliced out of the stack
+            k_pages, v_pages = k_pages[layer], v_pages[layer]
         kernel = functools.partial(_resident_kernel, **kw)
         pool_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
         grid, scratch, semantics = (b,), [], ("parallel",)
         pools = (k_pages, v_pages)
         # the pools on top of the scoped VMEM Mosaic gives a kernel by default
+        pool_bytes = 2 * pool * _page_vmem_bytes(page, kv, d, k_pages.dtype)
         params = dict(vmem_limit_bytes=pool_bytes + SCOPED_VMEM)
+        prefetch = (lens, jnp.asarray(page_table, jnp.int32))
     else:
+        # stacked pools are read where they lie: no copy of a layer's pool
+        if layer is None:
+            k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
         kernel = functools.partial(_streamed_kernel, **kw)
+        nb = pl.cdiv(maxp, ppb)
         slots = [
-            pl.BlockSpec((1, page, kv, d), _page_index_map(j, page=page, window=window, ppb=ppb))
+            pl.BlockSpec(
+                (None, 1, page, kv, d),
+                lambda bi, blk, lens, pages, layer, j=j: (
+                    layer[0], pages[(bi * nb + blk) * ppb + j], 0, 0, 0),
+            )
             for j in range(ppb)
         ]
         pool_specs = slots * 2
-        grid, semantics = (b, pl.cdiv(maxp, ppb)), ("parallel", "arbitrary")
+        grid, semantics = (b, nb), ("parallel", "arbitrary")
         scratch = [
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -280,8 +308,14 @@ def paged_decode_attention(
         ]
         pools = (k_pages,) * ppb + (v_pages,) * ppb
         params = {}
+        prefetch = (
+            lens,
+            _slot_pages(lens, jnp.asarray(page_table, jnp.int32),
+                        page=page, window=window, ppb=ppb),
+            jnp.asarray(layer, jnp.int32).reshape(1),
+        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=[row_spec, *pool_specs],
         out_specs=row_spec,
@@ -293,12 +327,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics, **params),
         interpret=interpret,
-    )(
-        jnp.asarray(cache_lens, jnp.int32),
-        jnp.asarray(page_table, jnp.int32),
-        q,
-        *pools,
-    )
+    )(*prefetch, q, *pools)
 
 
 def paged_decode_attention_sharded(

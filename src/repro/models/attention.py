@@ -482,6 +482,7 @@ def attention_decode_step_paged(
     cache_len: jax.Array,   # [B] (or scalar) tokens already resident per row
     cap: jax.Array,         # [B] token capacity per row (0 = inactive row)
     window: int,
+    layer: jax.Array | None = None,
 ):
     """One-token decode against the shared KV page pool.  x: [B,1,D].
 
@@ -492,6 +493,10 @@ def attention_decode_step_paged(
     rows decoding past their chunk — write the pool's trash page and attend
     over at most ``cap`` tokens, so they can never corrupt live sequences.
 
+    With ``layer``, the pools are stacked over the layers (``[L, P+1, page,
+    KV, Dh]``): the token lands in that layer's pool in place and the
+    kernel reads it there.
+
     Returns (out [B,1,D], new_k_pool, new_v_pool).
     """
 
@@ -499,7 +504,7 @@ def attention_decode_step_paged(
 
     b = x.shape[0]
     hd, nh, nkv = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
-    n_pages, page = k_pool.shape[0] - 1, k_pool.shape[1]
+    n_pages, page = k_pool.shape[-4] - 1, k_pool.shape[-3]
     maxp = page_table.shape[1]
     pos_b = jnp.broadcast_to(jnp.atleast_1d(cache_len), (b,)).astype(jnp.int32)
     cap_b = jnp.broadcast_to(jnp.atleast_1d(cap), (b,)).astype(jnp.int32)
@@ -512,12 +517,13 @@ def attention_decode_step_paged(
     page_idx = jnp.minimum(pos_b // page, maxp - 1)
     slot = page_table[jnp.arange(b), page_idx] * page + pos_b % page
     slot = jnp.where(pos_b < cap_b, slot, n_pages * page)  # trash when full
-    flat_shape = ((n_pages + 1) * page, nkv, hd)
+    flat_shape = k_pool.shape[:-4] + ((n_pages + 1) * page, nkv, hd)
+    at = slot if layer is None else (layer, slot)
     k_pool = (
-        k_pool.reshape(flat_shape).at[slot].set(k[:, 0].astype(k_pool.dtype))
+        k_pool.reshape(flat_shape).at[at].set(k[:, 0].astype(k_pool.dtype))
     ).reshape(k_pool.shape)
     v_pool = (
-        v_pool.reshape(flat_shape).at[slot].set(v[:, 0].astype(v_pool.dtype))
+        v_pool.reshape(flat_shape).at[at].set(v[:, 0].astype(v_pool.dtype))
     ).reshape(v_pool.shape)
 
     lens_eff = jnp.minimum(pos_b + 1, cap_b)
@@ -525,7 +531,7 @@ def attention_decode_step_paged(
     # call, and no table names the trash page
     out = kops.paged_decode_attention(
         q[:, 0], k_pool, v_pool, page_table, lens_eff,
-        window=window, logit_cap=cfg.attn_logit_softcap,
+        window=window, logit_cap=cfg.attn_logit_softcap, layer=layer,
     )[:, None]
     out = out.reshape(b, 1, nh * hd) @ params["wo"].astype(x.dtype)
     return out, k_pool, v_pool

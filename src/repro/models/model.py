@@ -348,23 +348,24 @@ class Model:
         return h2, combine
 
     def _block_step(self, spec, p, x, cache, cache_len, enc_out=None, enc_pos=None,
-                    paged=None):
+                    paged=None, layer=None):
         """Single-token decode block apply.
 
         ``paged``: a ``(page_table [B, MAXP], cap [B])`` pair when the cache
         holds paged attention entries (``kp``/``vp`` page pools) instead of
         dense per-slot slabs; non-attention block state is identical in both
-        modes.  Dense mode (``paged=None``) is the parity oracle.
+        modes.  Dense mode (``paged=None``) is the parity oracle.  ``layer``:
+        the pools are every layer's, stacked, and this block is that layer.
         """
 
         x, new_cache = self._block_mix_step(
-            spec, p, x, cache, cache_len, enc_out, enc_pos, paged=paged
+            spec, p, x, cache, cache_len, enc_out, enc_pos, paged=paged, layer=layer
         )
         x, _ = self._block_ffn(spec, p, x)
         return x, new_cache
 
     def _block_mix_step(self, spec, p, x, cache, cache_len, enc_out=None,
-                        enc_pos=None, paged=None):
+                        enc_pos=None, paged=None, layer=None):
         """Mixer half of ``_block_step`` (pre-FFN) — see ``_block_mix_seq``."""
 
         cfg = self.cfg
@@ -376,7 +377,7 @@ class Model:
             window = self._window_for(spec, capacity)
             out, kp, vp = attn.attention_decode_step_paged(
                 h, p["attn"], cfg, cache["kp"], cache["vp"],
-                page_table, cache_len, cap, window,
+                page_table, cache_len, cap, window, layer=layer,
             )
             new_cache = dict(cache)
             new_cache["kp"], new_cache["vp"] = kp, vp
@@ -477,22 +478,33 @@ class Model:
 
     def _run_unit_step(self, params_unit, x, cache_unit, cache_len, enc_out=None, enc_pos=None,
                        paged=None):
-        def body(x, xs):
-            p_list, c_list = xs
-            new_c = []
+        # page pools ride the layer scan's carry, stacked: each layer writes
+        # its token into its own pool in place and the kernel reads it
+        # there.  As scan inputs and outputs, each layer's pool would be
+        # copied out and back at every decode step.
+        whole = [paged is not None and "kp" in c for c in cache_unit]
+
+        def body(carry, xs):
+            x, pools = carry
+            p_list, c_list, layer = xs
+            new_c, new_pools = [], []
             for j, spec in enumerate(self.unit):
                 x, cj = self._block_step(
-                    spec, p_list[j], x, c_list[j], cache_len, enc_out, enc_pos,
-                    paged=paged,
+                    spec, p_list[j], x, pools[j] if whole[j] else c_list[j], cache_len,
+                    enc_out, enc_pos, paged=paged, layer=layer if whole[j] else None,
                 )
-                new_c.append(cj)
-            return x, tuple(new_c)
+                new_pools.append(cj if whole[j] else None)
+                new_c.append(None if whole[j] else cj)
+            return (x, tuple(new_pools)), tuple(new_c)
 
-        x, new_cache = jax.lax.scan(
-            body, x, (tuple(params_unit), tuple(cache_unit)),
+        pools = tuple(c if w else None for c, w in zip(cache_unit, whole))
+        per_layer = tuple(None if w else c for c, w in zip(cache_unit, whole))
+        (x, pools), new_cache = jax.lax.scan(
+            body, (x, pools),
+            (tuple(params_unit), per_layer, jnp.arange(self.repeats, dtype=jnp.int32)),
             unroll=self.repeats <= self.STEP_UNROLL_MAX,
         )
-        return x, list(new_cache)
+        return x, [p if w else c for p, c, w in zip(pools, new_cache, whole)]
 
     # ------------------------------------------------------------------
     # embeddings / inputs

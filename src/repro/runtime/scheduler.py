@@ -62,7 +62,9 @@ Each window boundary runs as four host spans on the profiler's clock
 ``sched.sync`` (the host waiting on the device for the window's tokens)
 and ``sched.harvest`` (the rest of the close).  ``sched.row_tokens``
 counts every decoded row-token by what its row held (``ROW_STATES``),
-from host integers tallied at dispatch.
+from host integers tallied at dispatch; ``sched.admitted_requests`` counts
+the requests the admission program (``_admit_for``) takes in, padded rows
+left out.
 """
 
 from __future__ import annotations
@@ -934,6 +936,9 @@ class ContinuousBatchingScheduler:
                 row_idx[i] = seq.row
                 lens[i] = self.prompt_len
                 caps[i] = self.cap_tokens
+            if self.obs is not None:
+                # the requests this admission program serves, padding left out
+                self.obs.metrics.counter("sched.admitted_requests").inc(len(new))
             self._pcache, self._logits = self._admit_for(n)(
                 self.params, self._pcache, self._logits,
                 jnp.asarray(obs), jnp.asarray(pt_new), jnp.asarray(row_idx),

@@ -6,7 +6,13 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.paged_attention import _page_index_map, paged_decode_attention
+from repro.kernels.paged_attention import (
+    _page_vmem_bytes,
+    _slot_pages,
+    paged_decode_attention,
+    pages_per_block,
+    pool_streamed,
+)
 from repro.runtime.kv_cache import OutOfPages, PageAllocator, PagedKVCache
 
 KEY = jax.random.PRNGKey(7)
@@ -30,6 +36,9 @@ PAGED_CASES = [
      (0, 1, 16, 17, 70, 0, 33, 48, 64, 69, 5, 0, 31, 70, 2, 50), 0, 0.0, jnp.bfloat16),
     # phi-3-vision's MHA widths
     (4, 32, 32, 96, 16, 21, 5, (70, 0, 17, 40), 0, 0.0, jnp.bfloat16),
+    # the same widths over a pool too large to read in place (STREAMED_POOL):
+    # the phi3v16 cell's path, one block of 5 page slots per row
+    (6, 32, 32, 96, 16, 130, 5, (70, 0, 17, 40, 1, 64), 0, 0.0, jnp.bfloat16),
     # rows of 26 and 19 live pages: more than one 16-page block each, from
     # a pool read in place from VMEM and from one too large for that
     (3, 8, 2, 64, 16, 121, 40, (600, 0, 300), 400, 30.0, jnp.float32),
@@ -83,9 +92,11 @@ def test_streamed_slots_copy_only_live_pages(window):
     the step before, so the pipeline copies nothing for it."""
 
     page, ppb, maxp = 16, 2, 5
+    nb = -(-maxp // ppb)
     lens = jnp.asarray([0, 70, 17, 0, 0, 33, 1, 80], jnp.int32)
     table = jnp.arange(lens.size * maxp, dtype=jnp.int32).reshape(-1, maxp) + 1
-    maps = [_page_index_map(j, page=page, window=window, ppb=ppb) for j in range(ppb)]
+    slots = np.asarray(_slot_pages(lens, table, page=page, window=window, ppb=ppb))
+    assert slots.shape == (lens.size * nb * ppb,)
     prev = [None] * ppb
     copies = live_pages = 0
     for bi in range(lens.size):
@@ -93,9 +104,9 @@ def test_streamed_slots_copy_only_live_pages(window):
         first = max(n - window, 0) // page if window else 0
         count = -(-n // page) - first
         live_pages += count
-        for blk in range(-(-maxp // ppb)):
-            for j, index_map in enumerate(maps):
-                idx = int(index_map(bi, blk, lens, table)[0])
+        for blk in range(nb):
+            for j in range(ppb):
+                idx = int(slots[(bi * nb + blk) * ppb + j])
                 if blk * ppb + j < count:
                     assert idx == int(table[bi, first + blk * ppb + j])
                 else:
@@ -105,6 +116,37 @@ def test_streamed_slots_copy_only_live_pages(window):
     # every live page once, plus the first step's copy into each slot
     # (row 0 is idle: page 0)
     assert copies == live_pages + ppb
+
+
+@pytest.mark.parametrize("pool", [21, 130])   # read in place; streamed
+def test_paged_decode_reads_one_layer_of_stacked_pools(pool):
+    """Pools stacked over 3 layers, read at layer 1, give what that layer's
+    pool alone gives, at phi-3-vision's MHA widths."""
+
+    b, h, kv, d, page, maxp = 4, 32, 32, 96, 16, 5
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (3, pool, page, kv, d), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (3, pool, page, kv, d), jnp.bfloat16)
+    table = jnp.asarray(np.random.default_rng(pool).permutation(pool)[: b * maxp]
+                        .reshape(b, maxp), jnp.int32)
+    lens = jnp.asarray([70, 0, 17, 40], jnp.int32)
+    got = paged_decode_attention(q, kp, vp, table, lens, jnp.int32(1), interpret=True)
+    want = paged_decode_attention(q, kp[1], vp[1], table, lens, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_mha_cases_over_the_budget_are_streamed():
+    """The MHA 32x96 bf16 case of 130 pages, and the phi3v16 cell's pool of
+    96 requests (480 pages and the trash page), exceed the budget for
+    pools read in place, so both take the streamed path with all 5 of a
+    row's pages in one block."""
+
+    assert _page_vmem_bytes(16, 32, 96, jnp.bfloat16) == 128 * 1024  # 96 lanes padded to 128
+    for pool, streamed in ((128, False), (130, True), (481, True)):
+        shape = jax.ShapeDtypeStruct((16, pool, 16, 32, 96), jnp.bfloat16)
+        assert pool_streamed(shape) is streamed
+    assert pages_per_block(5, 16, 32, 96, jnp.bfloat16) == 5
 
 
 def test_paged_matches_dense_decode_ref():

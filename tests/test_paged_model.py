@@ -85,6 +85,50 @@ def test_paged_decode_chunk_bit_identical_to_dense(arch):
     np.testing.assert_array_equal(np.asarray(toks_dense), np.asarray(toks_paged))
 
 
+def _scans(jaxpr):
+    """Every ``scan`` equation of ``jaxpr``, nested ones included."""
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scans(sub)
+
+
+@pytest.mark.parametrize("arch", [
+    "phi-3-vision-4.2b", "h2o-danube-3-4b", "gemma2-9b", "jamba-1.5-large-398b",
+    "seamless-m4t-medium",
+])
+def test_paged_decode_chunk_stacked_pools_ride_the_layer_scan_carry(arch):
+    """The layer scan of a paged decode step carries the page pools stacked
+    over the layers, so each layer writes and reads its own in place: a
+    pool handed to the scan as an input and output would be copied out
+    and back at every step."""
+
+    cfg, model, params = _stack(arch)
+    b, page, maxp = 2, 8, 4
+    spec = PagedSpec(num_pages=b * maxp, page_size=page, max_pages_per_seq=maxp)
+    cache = model.init_paged_cache(b, spec)
+    pools = {a.shape for c in cache["unit"] if "kp" in c for a in (c["kp"], c["vp"])}
+    assert pools
+    batch, tok = _batch_for(cfg, model, np.random.default_rng(0), b)
+    logits = jax.eval_shape(lambda p, bt: model.prefill(p, bt, extra=0)[0], params, batch)
+    jaxpr = jax.make_jaxpr(
+        lambda p, l, c: model.decode_chunk(p, l, c, 2, tok.action_base)
+    )(params, logits, cache).jaxpr
+    carried = 0
+    for eqn in _scans(jaxpr):
+        k, n = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = [tuple(v.aval.shape[1:]) for v in eqn.invars[k + n:]]
+        assert not any(s[-4:] == pool[-4:] for s in xs for pool in pools)
+        carried += sum(v.aval.shape in pools for v in eqn.invars[k:k + n])
+    assert carried >= len(pools)
+
+
 # ---------------------------------------------------------------------------
 # single-step paged attention: ragged lengths, windows, trash isolation
 # ---------------------------------------------------------------------------

@@ -33,6 +33,9 @@ WIDTHS = {
     "h2o-danube3": (32, 8, 120, 4096, 4096 // PAGE + 1, 8),
     # the benchmark's danube3 cell: 16 rows of 70 tokens, global attention
     "h2o-danube3-cell": (32, 8, 120, 0, 5, 16),
+    # the benchmark's phi3v16 cell: 96 rows of 70 tokens over a 480-page
+    # pool, too large to read in place, so the pages are streamed
+    "phi-3-vision-cell": (32, 32, 96, 0, 5, 96),
 }
 # the pattern bench/programs.json reads the kernel's device time by
 KERNEL_NAME = "paged_decode_attention"
