@@ -30,8 +30,11 @@ window-closing call performs the window's only host sync, harvesting every
 finished chunk at once.  Admission, completion, and page release happen
 only at these boundaries; a ``cancel`` landing mid-window marks the
 sequence dead and the boundary frees its pages — never while a donated
-in-flight buffer might still write them.  ``scan_rounds=1`` degenerates to
-the classic one-round-per-call loop (dispatch + harvest in the same call).
+in-flight buffer might still write them.  A released row's capacity is
+zeroed on the device by one program (``zero_caps``) for every row released
+since the last boundary, at the start of the next admission, before any
+program can read it.  ``scan_rounds=1`` degenerates to the classic
+one-round-per-call loop (dispatch + harvest in the same call).
 
 Split lanes come in two flavours: the **serial** lane ping-pongs every token
 through the host (the deployment-faithful per-robot loop), while the
@@ -64,7 +67,8 @@ and ``sched.harvest`` (the rest of the close).  ``sched.row_tokens``
 counts every decoded row-token by what its row held (``ROW_STATES``),
 from host integers tallied at dispatch; ``sched.admitted_requests`` counts
 the requests the admission program (``_admit_for``) takes in, padded rows
-left out.
+left out; ``sched.release_flushes`` counts the ``zero_caps`` programs and
+``sched.released_rows`` the rows they zeroed.
 """
 
 from __future__ import annotations
@@ -93,6 +97,16 @@ from repro.runtime.kv_cache import PageAllocator, PagedSpec, donating_jit
 DEFAULT_PAGE_SIZE = 16
 # what a decode row's token held, for ``sched.row_tokens{state=...}``
 ROW_STATES = ("live", "past", "idle", "cancelled")
+
+
+def zero_caps(cap, mask):
+    """Capacity 0 on every row ``mask`` holds, the others unchanged."""
+
+    return jnp.where(mask, 0, cap)
+
+
+# ``cap`` is donated: the update is in place and keeps the array's layout
+_zero_caps = donating_jit(zero_caps, donate_argnums=(0,))
 
 
 def _bucket(n: int) -> int:
@@ -313,6 +327,9 @@ class ContinuousBatchingScheduler:
         self._queue: Deque[ChunkRequest] = deque()
         self._seqs: Dict[int, _Sequence] = {}    # row -> sequence
         self._free_rows: List[int] = list(range(rows0))
+        # rows released since the last ``_flush_releases``: their device
+        # capacity is still the old sequence's until the flush zeroes it
+        self._release_mask = np.zeros(rows0, bool)
         # lane-key-keyed split-lane registry: plain layer cuts key by their
         # int cut (backwards compatible), expert-offload lanes by
         # ``(cut, offload)`` — so a plain lane and an offload lane may share
@@ -452,11 +469,12 @@ class ContinuousBatchingScheduler:
         The redundancy-aware fleet loop calls this when a contact-phase
         trigger fires while a previous request is still decoding.  Queued
         requests are plain queue removals.  An in-flight sequence is freed
-        immediately — *unless* it belongs to the currently dispatched scan
-        window: the donated in-flight scan still writes its pages and row,
-        so the sequence is only MARKED dead here and the window boundary
-        releases it (without emitting a result).  Freeing early would let
-        the next admission reuse pages the scan is still writing.  Returns
+        immediately (its capacity zeroed at the next admission) — *unless*
+        it belongs to the currently dispatched scan window: the donated
+        in-flight scan still writes its pages and row, so the sequence is
+        only MARKED dead here and the window boundary releases it (without
+        emitting a result).  Freeing early would let the next admission
+        reuse pages the scan is still writing.  Returns
         ``False`` when nothing was in flight (e.g. the preemption raced the
         chunk's final decode round) — nothing is double-freed.
         """
@@ -630,6 +648,7 @@ class ContinuousBatchingScheduler:
         self._queue.clear()
         self._seqs.clear()
         self._free_rows = list(range(self.rows))
+        self._release_mask = np.zeros(self.rows, bool)  # every cap zeroed below
         # same allocator object: lifetime alloc/free counters survive the
         # reset while the high-water mark restarts, so per-episode
         # ``PoolStats.high_water`` stays meaningful on a reused scheduler
@@ -728,6 +747,9 @@ class ContinuousBatchingScheduler:
                     self._pcache["pt"], "batch", None
                 )
                 self._pcache["cap"] = logical_shard(self._pcache["cap"], "batch")
+        self._release_mask = np.concatenate(
+            [self._release_mask, np.zeros(pad, bool)]
+        )
         self._free_rows.extend(range(old, new))
         self.rows = new
 
@@ -873,10 +895,13 @@ class ContinuousBatchingScheduler:
         suffixes (any cut) and cloud-only robots compete for the same pages
         in submission order, so no kind can starve another.  A head whose
         ``earliest_round`` lies in the future holds its lane back this round
-        (deferred admissions keep their FIFO slot).  The whole admission is
+        (deferred admissions keep their FIFO slot).  The capacity of every
+        row released since the last admission is zeroed first, so a row
+        re-admitted here keeps its new capacity.  The whole admission is
         the ``sched.admit`` span."""
 
         with self._span("sched.admit") as span:
+            self._flush_releases()
             if self._prefill_device is not None and self._pending_admit:
                 # disaggregation phase 2: last boundary's prefill-device
                 # results merge into the live pool before any new
@@ -946,13 +971,31 @@ class ContinuousBatchingScheduler:
             )
 
     def _release(self, seq: _Sequence) -> None:
-        """Return pages + row; zero the row's capacity so the (still
-        batched) row can never write into pages a later admission reuses."""
+        """Return pages + row, and mark the row for ``_flush_releases``,
+        which zeroes its capacity before any program runs again, so the
+        (still batched) row can never write into pages a later admission
+        reuses."""
 
         self.allocator.free(seq.pages)
         del self._seqs[seq.row]
         self._free_rows.append(seq.row)
-        self._pcache["cap"] = self._pcache["cap"].at[seq.row].set(0)
+        self._release_mask[seq.row] = True
+
+    def _flush_releases(self) -> None:
+        """Zero the capacity of every row released since the last flush,
+        in one program; with none released, dispatch nothing."""
+
+        n = int(self._release_mask.sum())
+        if not n:
+            return
+        # a fresh mask, not a cleared one: the dispatched copy may still
+        # read the host buffer
+        mask, self._release_mask = self._release_mask, np.zeros(self.rows, bool)
+        self._pcache["cap"] = _zero_caps(self._pcache["cap"], mask)
+        if self.obs is not None:
+            m = self.obs.metrics
+            m.counter("sched.release_flushes").inc()
+            m.counter("sched.released_rows").inc(n)
 
     # ------------------------------------------------------------------
     # prefill/decode disaggregation (``prefill_group``)
