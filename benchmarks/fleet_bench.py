@@ -1,28 +1,23 @@
 """Fleet-scale serving benchmark: 1k+ robots per host, trace-driven load.
 
-Two measurements back the vectorized fleet tick:
+Two measurements:
 
-  * **tick speedup** — the same ``serve_fleet`` run (smoke model, same
-    decode windows, bit-identical actions) through the vectorized
-    array-at-a-time tick vs the preserved legacy per-robot Python loop,
-    compared on HOST tick overhead (``host_s`` = wall − decision core −
-    engine; on CPU the shared Pallas-interpret decode swamps total wall).
-    This ratio is the CI regression gate.
+  * **tick scaling** — host tick overhead of ``serve_fleet`` (``host_s`` =
+    wall − decision core − engine) at 64 / 256 / 1024 robots against a
+    fixed decode pool, which should grow sublinearly in fleet size;
   * **trace-driven SLO run** — ``runtime/fleet.py`` drives the real
     ``ContinuousBatchingScheduler`` with a Poisson or bursty arrival
-    trace, episode churn, and full SLO accounting through the PR 7
+    trace, episode churn, and full SLO accounting through the
     observability layer; percentiles land in ``BENCH_fleet.json``.
 
-A third table shows host tick overhead growing sublinearly in fleet size
-(vectorized tick at 64 / 256 / 1024 robots against a fixed decode pool).
+Run on the CPU, both are CPU timings, not chip speed.
 
 Emits the ``name,us_per_call,derived`` CSV contract and merges raw
 numbers into ``BENCH_fleet.json`` (keys carry the fleet size, so the CI
 smoke at 256 robots never clobbers the committed 1k-robot record).
 
     PYTHONPATH=src python benchmarks/fleet_bench.py [--fleet 1024]
-    PYTHONPATH=src python benchmarks/fleet_bench.py --smoke \
-        --check-min-tick-speedup 2.0
+    PYTHONPATH=src python benchmarks/fleet_bench.py --smoke
 """
 
 from __future__ import annotations
@@ -31,7 +26,6 @@ import json
 import os
 
 import jax
-import numpy as np
 
 from repro.obs.clock import clock
 
@@ -63,64 +57,8 @@ def _update_json(path, out):
         json.dump(merged, f, indent=2)
 
 
-def bench_tick_rows(n_robots: int = 1024, steps: int = 60):
-    """Vectorized vs legacy serving tick at ``n_robots``, same engine.
-
-    Both runs serve the identical workload (bit-identical actions, same
-    decode windows), so their jitted decision-core and engine
-    (``sched.step``) time cancel — on this CPU container the engine's
-    Pallas-interpret decode dominates total wall equally in both paths.
-    The gated ratio therefore compares HOST tick overhead (``host_s`` =
-    wall − core − engine): frame building, trigger bookkeeping,
-    submit/cancel calls, and harvest handling — exactly the per-robot
-    Python the vectorized tick turns into array ops.  Total ticks/s for
-    both paths is reported alongside.
-    """
-
-    from repro.launch.serve import serve_fleet
-
-    model, params, tok = _stack()
-    common = dict(
-        n_robots=n_robots, max_steps=steps, max_slots=8,
-        scan_rounds=SCAN_ROUNDS, trigger="rapid", seed=0, verbose=False,
-    )
-    # warm the jit caches ([R]-shaped decision core + engine variants) on a
-    # short run before timing either path
-    serve_fleet(model, params, tok, tick="vectorized", **{**common, "max_steps": 12})
-    vec = serve_fleet(model, params, tok, tick="vectorized", **common)
-    leg = serve_fleet(model, params, tok, tick="legacy", **common)
-    assert (vec["actions"] == leg["actions"]).all(), "tick paths diverged"
-    vec_host_ms = vec["host_s"] / vec["steps"] * 1e3
-    leg_host_ms = leg["host_s"] / leg["steps"] * 1e3
-    speedup = leg_host_ms / vec_host_ms
-    vec_tps = vec["steps"] / vec["wall_s"]
-    leg_tps = leg["steps"] / leg["wall_s"]
-    out = {
-        f"f{n_robots}_host_ms_tick_vec": vec_host_ms,
-        f"f{n_robots}_host_ms_tick_legacy": leg_host_ms,
-        f"f{n_robots}_tick_speedup": speedup,
-        f"f{n_robots}_ticks_s_vec": vec_tps,
-        f"f{n_robots}_ticks_s_legacy": leg_tps,
-        f"f{n_robots}_engine_ms_tick": vec["engine_s"] / vec["steps"] * 1e3,
-        f"f{n_robots}_core_ms_tick": vec["core_s"] / vec["steps"] * 1e3,
-        "tick_speedup_fleet": n_robots,
-        "tick_speedup": speedup,
-        "scan_rounds": SCAN_ROUNDS,
-    }
-    rows = [
-        f"{n_robots} robots x {steps} ticks (host overhead/tick): "
-        f"vectorized={vec_host_ms:.2f}ms legacy={leg_host_ms:.2f}ms "
-        f"({speedup:.1f}x, bit-identical actions)",
-        f"total: vectorized={vec_tps:.1f} ticks/s legacy={leg_tps:.1f} "
-        f"ticks/s (shared engine decode "
-        f"{out[f'f{n_robots}_engine_ms_tick']:.0f}ms/tick + core "
-        f"{out[f'f{n_robots}_core_ms_tick']:.1f}ms/tick dominate wall here)",
-    ]
-    return rows, out
-
-
 def bench_scaling_rows(fleets=(64, 256, 1024), steps: int = 40):
-    """Host tick overhead of the vectorized path as the fleet grows 16x.
+    """Host tick overhead of ``serve_fleet`` as the fleet grows 16x.
 
     The decode pool is fixed, so ``host_s`` growth (wall minus decision
     core minus engine) is pure orchestration cost.  ``sublinear_ratio`` =
@@ -215,41 +153,24 @@ def bench_trace_rows(
 
 def main(argv=None):
     import argparse
-    import sys
 
     p = argparse.ArgumentParser()
     p.add_argument("--fleet", type=int, default=1024,
-                   help="fleet size for the trace run and tick comparison")
+                   help="fleet size for the trace run")
     p.add_argument("--horizon", type=int, default=320,
                    help="trace-run length in control ticks")
     p.add_argument("--arrivals", choices=("poisson", "bursty"),
                    default="poisson")
-    p.add_argument("--steps", type=int, default=60,
-                   help="ticks per run in the vectorized-vs-legacy comparison")
     p.add_argument("--smoke", action="store_true",
                    help="CI shape: 256 robots, short horizon, 64->256 scaling")
     p.add_argument("--skip-scaling", action="store_true")
-    p.add_argument(
-        "--check-min-tick-speedup", type=float, default=None, metavar="FLOOR",
-        help="exit non-zero if the vectorized tick speedup lands below FLOOR "
-             "(the CI regression gate for the fleet-tick vectorization)",
-    )
     args = p.parse_args(argv)
     if args.smoke:
         args.fleet = min(args.fleet, 256)
         args.horizon = min(args.horizon, 160)
-        args.steps = min(args.steps, 40)
 
     path = os.path.join(os.path.dirname(__file__), "..", "BENCH_fleet.json")
     print("name,us_per_call,derived")
-
-    t0 = clock()
-    rows, tick_out = bench_tick_rows(n_robots=args.fleet, steps=args.steps)
-    _update_json(path, tick_out)
-    print(f"fleet_tick_speedup,{(clock() - t0) * 1e6:.0f},"
-          f"{round(tick_out['tick_speedup'], 2)}")
-    for r in rows:
-        print("   ", r)
 
     if not args.skip_scaling:
         fleets = (64, 256) if args.smoke else (64, 256, 1024)
@@ -269,17 +190,6 @@ def main(argv=None):
     print(f"fleet_trace_slo,{(clock() - t0) * 1e6:.0f},{args.fleet}")
     for r in rows:
         print("   ", r)
-
-    if args.check_min_tick_speedup is not None:
-        got = tick_out["tick_speedup"]
-        floor = args.check_min_tick_speedup
-        if got < floor:
-            print(
-                f"FAIL: fleet tick_speedup={got:.3f} below the recorded "
-                f"floor {floor:.3f}", file=sys.stderr,
-            )
-            sys.exit(1)
-        print(f"fleet tick gate OK: {got:.3f} >= {floor:.3f}")
 
 
 if __name__ == "__main__":

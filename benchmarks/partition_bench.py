@@ -182,106 +182,6 @@ def bench_rows(offload_fraction=None, out_path=None):
     return rows, n_split
 
 
-def bench_pipelined_rows(out_path=None):
-    """Measured split serving: serial per-token ping-pong vs the pipelined
-    device-resident window, per network profile.
-
-    Four partitioned robots drain through one scheduler split lane twice —
-    ``pipelined=False`` (the deployment-faithful per-token host ping-pong:
-    two channel legs and two host syncs per decoded token) and
-    ``pipelined=True`` (one fused jitted scan per window; the cut
-    activation never surfaces to the host).  Wall-clock measures the
-    compute side; the channel is priced by the planner's ``interior_net_ms``
-    model for the profile — serial pays a full RTT per token, pipelined the
-    overlapped ``rtt/2 + ship`` — and the recorded tok/s combines both, so
-    the row reflects what the planner's pipelined pricing claims end-to-end.
-    """
-
-    import jax
-    import numpy as np
-
-    from repro.configs import get_smoke_config
-    from repro.data.pipeline import EpisodeTokenizer
-    from repro.models.model import Model
-    from repro.partition.executor import PartitionExecutor
-    from repro.partition.planner import NETWORK_PROFILES, interior_net_ms
-    from repro.runtime.scheduler import ContinuousBatchingScheduler
-
-    cfg = get_smoke_config("openvla-7b")
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    tok = EpisodeTokenizer(cfg.vocab_size)
-    ex = PartitionExecutor(model, params, cut_layer=1)
-
-    rng = np.random.default_rng(5)
-    n_req = 4
-    reqs = [
-        (
-            rng.normal(0, 0.5, (1, 7)).astype(np.float32),
-            rng.normal(0, 0.5, (1, 7)).astype(np.float32),
-        )
-        for _ in range(n_req)
-    ]
-    prompt_len, n_decode = 14, 56
-    act_tok = cfg.d_model * 2.0  # bf16 activations on the wire
-
-    def run(pipelined: bool) -> float:
-        sched = ContinuousBatchingScheduler(
-            model, params, tok, max_slots=n_req,
-            scan_rounds=4 if pipelined else 1,
-        )
-        sched.attach_partition(ex, rows=n_req, pipelined=pipelined)
-
-        def once():
-            sched.reset()
-            for i, (qd, tau) in enumerate(reqs):
-                sched.submit(i, qd, tau, partitioned=True)
-            t0 = time.time()
-            done = 0
-            while done < n_req:
-                done += len(sched.step())
-            return time.time() - t0
-
-        once()  # warm the jit caches
-        return min(once(), once())
-
-    compute_s = {"serial": run(False), "pipelined": run(True)}
-    rows = []
-    cells = {}
-    n_ok = 0
-    for profile, channel in NETWORK_PROFILES.items():
-        cell = {}
-        for mode in ("serial", "pipelined"):
-            net = interior_net_ms(
-                channel, prompt_len * act_tok, act_tok, n_decode,
-                pipelined=mode == "pipelined",
-            )
-            total_s = compute_s[mode] + n_req * net["total_ms"] / 1e3
-            cell[f"{mode}_tok_s"] = round(n_req * n_decode / total_s, 1)
-            cell[f"{mode}_net_ms"] = round(net["total_ms"], 2)
-        cell["speedup"] = round(cell["pipelined_tok_s"] / cell["serial_tok_s"], 3)
-        n_ok += cell["pipelined_tok_s"] >= cell["serial_tok_s"]
-        cells[profile] = cell
-        rows.append(
-            f"{profile}: serial={cell['serial_tok_s']:.0f} tok/s "
-            f"pipelined={cell['pipelined_tok_s']:.0f} tok/s "
-            f"({cell['speedup']:.1f}x)"
-        )
-
-    if out_path is None:
-        out_path = os.path.abspath(
-            os.path.join(os.path.dirname(__file__), "..", "BENCH_partition.json")
-        )
-    merged = {}
-    if os.path.exists(out_path):
-        with open(out_path) as f:
-            merged = json.load(f)
-    merged["pipelined_split_tok_s"] = cells
-    with open(out_path, "w") as f:
-        json.dump(merged, f, indent=2)
-    return rows, n_ok
-
-
 def check_2d_never_worse() -> int:
     """CI gate: the 2-D plan (and its executable restriction) must be no
     worse than the 1-D plan on every architecture x profile cell.
@@ -340,11 +240,6 @@ def main(argv=None):
     t0 = time.time()
     rows, derived = bench_rows()
     print(f"partition_planner_split_cells,{(time.time() - t0) * 1e6:.0f},{derived}")
-    for r in rows:
-        print("   ", r)
-    t0 = time.time()
-    rows, derived = bench_pipelined_rows()
-    print(f"pipelined_split_profiles_ok,{(time.time() - t0) * 1e6:.0f},{derived}")
     for r in rows:
         print("   ", r)
 

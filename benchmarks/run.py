@@ -38,7 +38,7 @@ def main(argv=None) -> None:
     from benchmarks import serving_bench
 
     _timed(
-        "serving_engine_speedup_8req",
+        "serving_engine_tok_s_8req",
         lambda: serving_bench.bench_rows()[:2], detail,
     )
     # paged engine: slot-bounded vs page-bounded admission concurrency
@@ -49,19 +49,6 @@ def main(argv=None) -> None:
         lambda: serving_bench.bench_sharded_rows()[:2], detail,
     )
 
-    # fleet-scale serving: vectorized tick vs the legacy per-robot loop
-    # (host overhead), CI-smoke fleet size to keep the harness run bounded
-    from benchmarks import fleet_bench
-
-    def _fleet():
-        rows, out = fleet_bench.bench_tick_rows(n_robots=256, steps=40)
-        fleet_bench._update_json(
-            __file__.replace("run.py", "../BENCH_fleet.json"), out
-        )
-        return rows, round(out["tick_speedup"], 2)
-
-    _timed("fleet_tick_speedup_256", _fleet, detail)
-
     # closed-loop redundancy-aware fleet vs always-offload (live engine)
     from benchmarks import trigger_bench
 
@@ -71,8 +58,6 @@ def main(argv=None) -> None:
     from benchmarks import partition_bench
 
     _timed("partition_planner_split_cells", partition_bench.bench_rows, detail)
-    # measured split serving: serial ping-pong vs pipelined windows
-    _timed("pipelined_split_profiles_ok", partition_bench.bench_pipelined_rows, detail)
     _timed("table1_vision_noise_degradation", tables.table1_vision_noise, detail)
     _timed("table3_simulation_speedup", tables.table3_simulation, detail)
     _timed("table4_realworld_speedup", tables.table4_real_world, detail)

@@ -3,13 +3,10 @@
 Measures action-token throughput of the cloud serving path on the smoke
 config (CPU container; the same harness runs compiled on TPU):
 
-  * ``loop`` — the seed ``CloudPolicy`` path: one jitted call and one
-    host↔device sync per decoded token;
   * ``fused`` — the on-device ``lax.scan`` chunk decoder (one sync per
     chunk), at batch 1 / 8 / 32;
-  * ``serve8_seed`` vs ``serve8_engine`` — eight concurrent requests served
-    the way the seed repo serves them (sequential batch-1 per-token loops,
-    as ``serve_episode`` does) vs one continuous-batching engine round-trip;
+  * ``serve8_engine`` — eight concurrent requests served by one
+    continuous-batching engine round-trip;
   * ``ragged`` vs ``gang`` — staggered arrivals admitted into in-flight
     decode batches vs gang-scheduling that drains the current batch first;
   * ``slotpool`` vs ``pagepool`` — the paged engine under a 16-request
@@ -73,42 +70,21 @@ def bench_rows():
     from repro.launch.serve import CloudPolicy
     from repro.runtime.scheduler import ContinuousBatchingScheduler
 
-    from repro.configs import get_smoke_config
-    from repro.models.model import Model
-
     model, params, tok = _stack()
-    # the seed decoded through the rolled layer scan; pin the loop baseline
-    # to it so the comparison measures the seed path, not this PR's model
-    seed_model = Model(get_smoke_config("openvla-7b"))
-    seed_model.STEP_UNROLL_MAX = 0
     rng = np.random.default_rng(0)
     out = {}
     rows = []
 
-    loop = CloudPolicy(seed_model, params, tok, fused=False)
-    fused = CloudPolicy(model, params, tok, fused=True)
+    fused = CloudPolicy(model, params, tok)
     for b in (1, 8, 32):
         qd, tau = _obs(rng, b)
-        tps_loop, _ = _tok_per_s(loop, qd, tau)
         tps_fused, _ = _tok_per_s(fused, qd, tau)
-        out[f"loop_tok_s_b{b}"] = tps_loop
         out[f"fused_tok_s_b{b}"] = tps_fused
-        rows.append(
-            f"b={b}: loop={tps_loop:.0f} tok/s fused={tps_fused:.0f} tok/s "
-            f"({tps_fused / tps_loop:.1f}x)"
-        )
+        rows.append(f"b={b}: fused={tps_fused:.0f} tok/s")
 
-    # --- eight concurrent requests: seed serving vs the batching engine ----
+    # --- eight concurrent requests through the batching engine ------------
     n_req = 8
     reqs = [_obs(rng, 1) for _ in range(n_req)]
-    for qd, tau in reqs:
-        loop(qd, tau)  # warm per-shape caches
-    t0 = clock()
-    for qd, tau in reqs:
-        loop(qd, tau)  # the seed serve_episode path: one robot at a time
-    dt_seed = clock() - t0
-    out["serve8_seed_tok_s"] = n_req * TOKENS_PER_CHUNK / dt_seed
-
     sched = ContinuousBatchingScheduler(
         model, params, tok, max_slots=n_req, scan_rounds=SCAN_ROUNDS
     )
@@ -143,12 +119,7 @@ def bench_rows():
     run_engine(stagger=False, gang=False)  # warm compile
     dt_engine, _ = run_engine(stagger=False, gang=False)
     out["serve8_engine_tok_s"] = n_req * TOKENS_PER_CHUNK / dt_engine
-    speedup = out["serve8_engine_tok_s"] / out["serve8_seed_tok_s"]
-    out["serve8_speedup"] = speedup
-    rows.append(
-        f"8 requests: seed(sequential loop)={out['serve8_seed_tok_s']:.0f} tok/s "
-        f"engine={out['serve8_engine_tok_s']:.0f} tok/s ({speedup:.1f}x)"
-    )
+    rows.append(f"8 requests: engine={out['serve8_engine_tok_s']:.0f} tok/s")
 
     # --- staggered arrivals: continuous (ragged) vs gang-scheduled --------
     # best-of-2 each: this ratio is a CI gate, so shave scheduler noise
@@ -180,7 +151,7 @@ def bench_rows():
 
     path = os.path.join(os.path.dirname(__file__), "..", "BENCH_serving.json")
     _update_json(path, out)
-    return rows, round(speedup, 2), out
+    return rows, round(out["serve8_engine_tok_s"], 1), out
 
 
 def _slo_fields(prefix: str, obs) -> dict:
@@ -388,7 +359,7 @@ def main(argv=None):
     print("name,us_per_call,derived")
     t0 = clock()
     rows, derived, out = bench_rows()
-    print(f"serving_engine_speedup_8req,{(clock() - t0) * 1e6:.0f},{derived}")
+    print(f"serving_engine_tok_s_8req,{(clock() - t0) * 1e6:.0f},{derived}")
     for r in rows:
         print("   ", r)
     t0 = clock()

@@ -30,9 +30,7 @@ distinct cuts decode in the same scheduler rounds against one KV page pool.
 With ``--arrivals poisson|bursty`` the fleet is served through the
 trace-driven harness instead: robots join at sampled arrival ticks, dwell
 for an exponential episode length, and leave — in-flight work is cancelled
-and KV pages are reclaimed without an engine reset.  The serving tick is
-the vectorized array-at-a-time path (``--tick legacy`` switches the flat
-fleet back to the per-robot loop for comparison).
+and KV pages are reclaimed without an engine reset.
 
     PYTHONPATH=src python examples/ecc_serving.py --task drawer_open
     PYTHONPATH=src python examples/ecc_serving.py --fleet 4
@@ -85,9 +83,6 @@ def main(argv=None):
                         "fixed fleet")
     p.add_argument("--mean-dwell", type=float, default=240.0,
                    help="mean episode dwell in ticks for --arrivals runs")
-    p.add_argument("--tick", default="vectorized",
-                   choices=["vectorized", "legacy"],
-                   help="fixed-fleet serving tick implementation")
     p.add_argument("--trigger", default="always", choices=["always", "rapid"],
                    help="fleet dispatch policy: always-offload or the "
                         "closed-loop redundancy-aware RAPID trigger")
@@ -230,7 +225,7 @@ def main(argv=None):
                 partition_executor=executor, split_robots=split,
                 robot_cuts=robot_cuts,
                 trigger=args.trigger, defer_hot_admission=args.defer_hot,
-                scan_rounds=args.scan_rounds, obs=mk_obs(), tick=args.tick,
+                scan_rounds=args.scan_rounds, obs=mk_obs(),
                 mesh=mesh, prefill_group=prefill_group,
             )
         if args.assign_cuts:
@@ -251,7 +246,6 @@ def main(argv=None):
                     trigger=args.trigger,
                     defer_hot_admission=args.defer_hot,
                     scan_rounds=args.scan_rounds, obs=mk_obs(),
-                    tick=args.tick,
                     mesh=mesh, prefill_group=prefill_group,
                 )
                 print(f"episode 2 robot cuts: {out['robot_cuts']} "

@@ -47,11 +47,7 @@ from repro.models.model import Model
 from repro.obs import Observability, build_slo_report
 from repro.obs.clock import clock
 from repro.robotics.episodes import generate_episode
-from repro.runtime.channel import (
-    ChannelConfig,
-    sample_latency_ms,
-    sample_latency_ms_batch,
-)
+from repro.runtime.channel import ChannelConfig, sample_latency_ms_batch
 from repro.runtime.policy import FleetTelemetry, PolicyConfig
 from repro.runtime import policy as rpolicy
 
@@ -59,11 +55,8 @@ from repro.runtime import policy as rpolicy
 class CloudPolicy:
     """Batched VLA serving: observation tokens -> k-step action chunk.
 
-    ``fused=True`` (default) decodes the whole ``chunk_len * n_joints`` token
-    chunk in one jitted ``lax.scan`` with zero host↔device syncs.
-    ``fused=False`` keeps the legacy per-token Python loop (one jitted call
-    and an ``np.asarray`` sync per token) — the baseline the serving bench
-    measures against; both produce bit-identical greedy chunks.
+    The whole ``chunk_len * n_joints`` token chunk decodes in one jitted
+    ``lax.scan`` with zero host↔device syncs.
 
     ``paged=True`` decodes through the model's paged KV mode — prompt KV is
     scattered into a page pool after prefill and attention reads go through
@@ -72,14 +65,13 @@ class CloudPolicy:
     """
 
     def __init__(self, model: Model, params, tokenizer: EpisodeTokenizer,
-                 chunk_len: int = 8, n_joints: int = 7, fused: bool = True,
+                 chunk_len: int = 8, n_joints: int = 7,
                  paged: bool = False, page_size: int = 16):
         self.model = model
         self.params = params
         self.tok = tokenizer
         self.chunk_len = chunk_len
         self.n_joints = n_joints
-        self.fused = fused
         self.paged = paged
         self.page_size = page_size
         n_steps = chunk_len * n_joints
@@ -87,7 +79,6 @@ class CloudPolicy:
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, extra=n_steps)
         )
-        self._decode = jax.jit(model.decode_step)
         self._decode_chunk = jax.jit(
             lambda p, logits, cache: model.decode_chunk(
                 p, logits, cache, n_steps, tokenizer.action_base
@@ -191,30 +182,15 @@ class CloudPolicy:
         obs = np.concatenate(
             [self.tok.encode_state(qd), self.tok.encode_state(tau)], axis=1
         )
-        batch = {"tokens": jnp.asarray(obs)}
+        tokens = jnp.asarray(obs)
         if self.paged:
-            fn = self._paged_chunk_for(obs.shape[0], obs.shape[1])
-            toks = np.asarray(fn(self.params, batch["tokens"]))
-            return self.tok.decode_action(toks).reshape(
-                -1, self.chunk_len, self.n_joints
-            )
-        logits, cache = self._prefill(self.params, batch)
-        if self.fused:
-            toks = np.asarray(self._decode_chunk(self.params, logits, cache))
+            toks = self._paged_chunk_for(*obs.shape)(self.params, tokens)
         else:
-            # legacy loop: greedy decode k*N action tokens one by one,
-            # masked to the action-bin range, syncing to host each step
-            acts = []
-            base = self.tok.action_base
-            tok = None
-            for _ in range(self.chunk_len * self.n_joints):
-                ls = logits[:, -1] if tok is None else logits[:, 0]
-                ls = ls.at[..., : base].set(-1e9)  # only action bins
-                tok = jnp.argmax(ls, axis=-1)[:, None]
-                acts.append(np.asarray(tok))
-                logits, cache = self._decode(self.params, tok, cache)
-            toks = np.concatenate(acts, axis=1)  # [B, k*N]
-        return self.tok.decode_action(toks).reshape(-1, self.chunk_len, self.n_joints)
+            logits, cache = self._prefill(self.params, {"tokens": tokens})
+            toks = self._decode_chunk(self.params, logits, cache)
+        return self.tok.decode_action(np.asarray(toks)).reshape(
+            -1, self.chunk_len, self.n_joints
+        )
 
 
 def serve_episode(
@@ -289,7 +265,6 @@ def serve_fleet(
     trigger_cfg: Optional[TriggerConfig] = None,
     record_streams: bool = False,
     obs: Optional[Observability] = None,
-    tick: str = "vectorized",
     verbose: bool = True,
 ):
     """A robot fleet served by one continuous-batching cloud engine.
@@ -356,32 +331,20 @@ def serve_fleet(
     Decoded actions are byte-identical with and without ``obs`` — no extra
     host↔device syncs are introduced.
 
-    ``tick`` selects the serving-tick implementation:
-
-      * ``"vectorized"`` (default) — array-at-a-time ticks: episode frames
-        pre-stacked to ``(T, R, N)`` and sliced per tick, one
-        ``submit_batch``/``cancel_batch`` scheduler call per tick,
-        ``in_flight``/split/defer bookkeeping as boolean/int arrays, one
-        batched ``decode_action`` and one batched jitter draw per harvest.
-        Host tick overhead is O(triggered robots) numpy work, not O(fleet)
-        Python — this is what serves 1k+ robots per host.
-      * ``"legacy"`` — the original per-robot Python loop (per-tick
-        ``np.stack`` over episode lists, per-robot ``submit``/``cancel``, a
-        Python ``in_flight`` set, per-result completion handling).  Kept as
-        the bit-for-bit parity reference and the baseline that
-        ``benchmarks/fleet_bench.py`` measures the tick speedup against.
-
-    Both paths produce bit-identical actions, telemetry counters, decision
-    streams, and latency samples (f32 decode; threefry jitter draws are
-    deterministic per (robot, ordinal) lane).
+    Each tick is array-at-a-time: episode frames are stacked once to
+    ``(T, R, N)`` and sliced per tick, the scheduler takes one
+    ``submit_batch`` and one ``cancel_batch`` call per tick, in-flight /
+    split / defer bookkeeping lives in ``[R]`` arrays, and each harvest
+    makes one batched ``decode_action`` and one batched jitter draw.  Host
+    tick overhead is O(triggered robots) numpy work, not O(fleet) Python.
+    Jitter draws are keyed per (robot, offload ordinal), so a robot's
+    latency stream does not depend on the order chunks complete in.
     """
 
     from repro.runtime.scheduler import ContinuousBatchingScheduler, _lane_order
 
     if trigger not in ("always", "rapid"):
         raise ValueError(f"trigger must be 'always' or 'rapid', got {trigger!r}")
-    if tick not in ("vectorized", "legacy"):
-        raise ValueError(f"tick must be 'vectorized' or 'legacy', got {tick!r}")
     all_tasks = tasks or ["pick_place", "drawer_open", "peg_insertion"]
     eps = [
         generate_episode(all_tasks[i % len(all_tasks)], seed=seed + i)
@@ -446,7 +409,6 @@ def serve_fleet(
     # harvested chunks in harvest order:
     # (robot id, submission round, action tokens)
     chunks: List[Tuple[int, int, np.ndarray]] = []
-    in_flight = set()
     # stochastic channel: every completed offload draws a jittered latency.
     # Keys fold in (robot id, per-robot offload ordinal), so each robot's
     # latency stream is reproducible across processes and fleet compositions
@@ -460,9 +422,8 @@ def serve_fleet(
     # decision core (dispatch + forcing its outputs to host), the engine's
     # ``sched.step`` (prefill + decode windows), and everything else — the
     # HOST tick overhead (frame building, trigger bookkeeping, submits,
-    # harvest handling) that the vectorized tick turns into array ops.
-    # ``sched.step`` was already clocked per tick for boundary telemetry,
-    # so only the core timer adds clock reads (two per tick, both paths).
+    # harvest handling).  ``sched.step`` is clocked per tick for boundary
+    # telemetry anyway, so only the core timer adds clock reads (two a tick).
     core_s = 0.0
     engine_s = 0.0
     # host-gap accounting per scan window: step() host time accumulates
@@ -474,191 +435,116 @@ def serve_fleet(
     prev_closes = 0
     t_start = clock()
 
-    if tick == "legacy":
-        # The original per-robot serving loop, preserved verbatim (including
-        # its per-tick ``np.stack`` over episode lists) as the parity
-        # reference and the fleet-tick benchmark baseline.
-        for t in range(t_len):
-            frame = KinematicFrame(
-                q=jnp.asarray(np.stack([ep.q[t] for ep in eps])),
-                qd=jnp.asarray(np.stack([ep.qd[t] for ep in eps])),
-                tau=jnp.asarray(np.stack([ep.tau[t] for ep in eps])),
-            )
-            c0 = clock()
-            state, dec = step_fn(state, frame)
-            trig = np.asarray(dec.offload)
-            pre = np.asarray(dec.preempt)
-            slot = np.asarray(dec.slot)
-            core_s += clock() - c0
-            telemetry.observe(dec)
-            # execute before this round's completions land: a chunk arriving
-            # in round t is first executable at t+1, as the dispatcher did
-            actions[t] = cached[rows, slot]
-            for r in np.flatnonzero(trig):
-                r = int(r)
-                if r in in_flight:
-                    if trigger != "rapid":
-                        continue  # previous request still decoding
-                    # contact-phase preemption: the stale in-flight sequence
-                    # is cancelled mid-decode and the fresh obs takes over
-                    if sched.cancel(r):
-                        telemetry.note_cancel(r)
-                    in_flight.discard(r)
+    # Frames are slices of (T, R, N) arrays stacked once, trigger
+    # bookkeeping lives in [R] boolean/int arrays, and each tick makes at
+    # most one cancel_batch + one submit_batch scheduler call and one
+    # batched decode/jitter call per harvest.  Within a tick every cancel
+    # lands before any submit: a cancel touches only its own robot's
+    # request, so the queues, the global FIFO ``order`` stamps and the
+    # telemetry counters read as if each robot cancelled then submitted
+    # in ascending robot order.
+    q_all = np.stack([ep.q[:t_len] for ep in eps], axis=1)
+    qd_all = np.stack([ep.qd[:t_len] for ep in eps], axis=1)
+    tau_all = np.stack([ep.tau[:t_len] for ep in eps], axis=1)
+    in_flight_mask = np.zeros(n_robots, bool)
+    split_mask = np.zeros(n_robots, bool)
+    # lane keys, not plain ints: an expert-offload robot carries a
+    # (cut, offload) tuple, so the per-robot routing array is object
+    cut_arr = np.full(n_robots, None, object)
+    for r, c in robot_cuts.items():
+        split_mask[r] = True
+        cut_arr[r] = c
+    # per-robot offload ordinal == len(offload_ms_by_robot[r]); kept as
+    # an array so the jitter keys batch without touching the lists
+    n_done = np.zeros(n_robots, np.int64)
+    for t in range(t_len):
+        frame = KinematicFrame(
+            q=jnp.asarray(q_all[t]),
+            qd=jnp.asarray(qd_all[t]),
+            tau=jnp.asarray(tau_all[t]),
+        )
+        c0 = clock()
+        state, dec = step_fn(state, frame)
+        trig = np.asarray(dec.offload)
+        pre = np.asarray(dec.preempt)
+        slot = np.asarray(dec.slot)
+        core_s += clock() - c0
+        telemetry.observe(dec)
+        # execute before this round's completions land: a chunk arriving
+        # in round t is first executable at t+1, as the dispatcher did
+        actions[t] = cached[rows, slot]
+        if trigger == "rapid":
+            # contact-phase preemption, batched: every firing robot with
+            # stale in-flight work cancels before the fresh submit
+            cancel_ids = np.flatnonzero(trig & in_flight_mask)
+            if cancel_ids.size:
+                hits = sched.cancel_batch(cancel_ids)
+                telemetry.note_cancels(cancel_ids[hits])
+                in_flight_mask[cancel_ids] = False
+            ids = np.flatnonzero(trig)
+        else:
+            # "always": fires landing while a request is in flight are
+            # skipped
+            ids = np.flatnonzero(trig & ~in_flight_mask)
+        if ids.size:
+            defer = None
+            if defer_hot_admission is not None:
                 # cancellation-aware admission: a preempting robot whose
-                # trigger is running hot gets its admission (not its queue
-                # slot) held one round, so an immediate re-fire cancels a
-                # queued request instead of a paid batched prefill
-                defer = int(
-                    defer_hot_admission is not None
-                    and bool(pre[r])
-                    and telemetry.preempts[r] / max(int(telemetry.fires[r]), 1)
-                    >= defer_hot_admission
-                )
-                sched.submit(
-                    r, eps[r].qd[t][None], eps[r].tau[t][None],
-                    partitioned=r in split_set,
-                    cut=robot_cuts.get(r),
-                    defer_rounds=defer,
-                )
-                in_flight.add(r)
-                n_off[r] += 1
-            t0 = clock()
-            results = sched.step()
-            step_s = clock() - t0
-            engine_s += step_s
-            window_host_ms += step_s * 1e3
-            if sched.window_closes > prev_closes:
-                telemetry.note_boundary(window_host_ms)
-                window_host_ms = 0.0
-                prev_closes = sched.window_closes
+                # trigger runs hot has its admission (not its queue slot)
+                # held one round, so an immediate re-fire cancels a queued
+                # request instead of a paid batched prefill
+                defer = (
+                    pre[ids]
+                    & (
+                        telemetry.preempts[ids]
+                        / np.maximum(telemetry.fires[ids], 1)
+                        >= defer_hot_admission
+                    )
+                ).astype(np.int64)
+            sched.submit_batch(
+                ids, qd_all[t][ids], tau_all[t][ids],
+                partitioned=split_mask[ids],
+                cuts=cut_arr[ids],
+                defer_rounds=defer,
+            )
+            in_flight_mask[ids] = True
+            n_off[ids] += 1
+        t0 = clock()
+        results = sched.step()
+        step_s = clock() - t0
+        engine_s += step_s
+        window_host_ms += step_s * 1e3
+        if sched.window_closes > prev_closes:
+            telemetry.note_boundary(window_host_ms)
+            window_host_ms = 0.0
+            prev_closes = sched.window_closes
+        if results:
+            # at most one outstanding request per robot, so a harvest
+            # never carries duplicate robot ids — batched scatter is safe
+            res_ids = np.fromiter(
+                (res.robot_id for res in results), np.int64,
+                count=len(results),
+            )
+            toks = np.stack([res.tokens for res in results])
             chunks.extend(
-                (res.robot_id, res.submitted_round, res.tokens) for res in results
+                (res.robot_id, res.submitted_round, res.tokens)
+                for res in results
             )
-            for res in results:
-                cached[res.robot_id] = tokenizer.decode_action(
-                    res.tokens
-                ).reshape(chunk_len, n_joints)
-                in_flight.discard(res.robot_id)
-                telemetry.note_completion(res.robot_id)
-                wait_rounds.append(res.completed_round - res.submitted_round)
-                rkey = jax.random.fold_in(
-                    jax.random.fold_in(net_key, res.robot_id),
-                    len(offload_ms_by_robot[res.robot_id]),
-                )
-                ms = sample_latency_ms(channel, chunk_len, rkey)
-                offload_ms.append(ms)
-                offload_ms_by_robot[res.robot_id].append(ms)
-    else:
-        # Vectorized fleet tick: frames are slices of (T, R, N) arrays
-        # stacked once, trigger bookkeeping lives in [R] boolean/int arrays,
-        # and each tick makes at most one cancel_batch + one submit_batch
-        # scheduler call and one batched decode/jitter call per harvest.
-        # Every step below is the array-at-a-time image of the legacy loop:
-        # cancels land before submits within a tick (cancel only touches
-        # that robot's own request, so all-cancels-then-all-submits in
-        # ascending robot order leaves the queues, the global FIFO ``order``
-        # stamps, and the telemetry counters identical to the interleaved
-        # per-robot sequence).
-        q_all = np.stack([ep.q[:t_len] for ep in eps], axis=1)
-        qd_all = np.stack([ep.qd[:t_len] for ep in eps], axis=1)
-        tau_all = np.stack([ep.tau[:t_len] for ep in eps], axis=1)
-        in_flight_mask = np.zeros(n_robots, bool)
-        split_mask = np.zeros(n_robots, bool)
-        # lane keys, not plain ints: an expert-offload robot carries a
-        # (cut, offload) tuple, so the per-robot routing array is object
-        cut_arr = np.full(n_robots, None, object)
-        for r, c in robot_cuts.items():
-            split_mask[r] = True
-            cut_arr[r] = c
-        # per-robot offload ordinal == len(offload_ms_by_robot[r]); kept as
-        # an array so the jitter keys batch without touching the lists
-        n_done = np.zeros(n_robots, np.int64)
-        for t in range(t_len):
-            frame = KinematicFrame(
-                q=jnp.asarray(q_all[t]),
-                qd=jnp.asarray(qd_all[t]),
-                tau=jnp.asarray(tau_all[t]),
+            cached[res_ids] = tokenizer.decode_action(toks).reshape(
+                len(results), chunk_len, n_joints
             )
-            c0 = clock()
-            state, dec = step_fn(state, frame)
-            trig = np.asarray(dec.offload)
-            pre = np.asarray(dec.preempt)
-            slot = np.asarray(dec.slot)
-            core_s += clock() - c0
-            telemetry.observe(dec)
-            # execute before this round's completions land: a chunk arriving
-            # in round t is first executable at t+1, as the dispatcher did
-            actions[t] = cached[rows, slot]
-            if trigger == "rapid":
-                # contact-phase preemption, batched: every firing robot with
-                # stale in-flight work cancels before the fresh submit
-                cancel_ids = np.flatnonzero(trig & in_flight_mask)
-                if cancel_ids.size:
-                    hits = sched.cancel_batch(cancel_ids)
-                    telemetry.note_cancels(cancel_ids[hits])
-                    in_flight_mask[cancel_ids] = False
-                ids = np.flatnonzero(trig)
-            else:
-                # "always": fires landing while a request is in flight are
-                # skipped (the legacy loop's ``continue``)
-                ids = np.flatnonzero(trig & ~in_flight_mask)
-            if ids.size:
-                defer = None
-                if defer_hot_admission is not None:
-                    # cancellation-aware admission (see the legacy branch),
-                    # as one vectorized preempt-rate comparison
-                    defer = (
-                        pre[ids]
-                        & (
-                            telemetry.preempts[ids]
-                            / np.maximum(telemetry.fires[ids], 1)
-                            >= defer_hot_admission
-                        )
-                    ).astype(np.int64)
-                sched.submit_batch(
-                    ids, qd_all[t][ids], tau_all[t][ids],
-                    partitioned=split_mask[ids],
-                    cuts=cut_arr[ids],
-                    defer_rounds=defer,
-                )
-                in_flight_mask[ids] = True
-                n_off[ids] += 1
-            t0 = clock()
-            results = sched.step()
-            step_s = clock() - t0
-            engine_s += step_s
-            window_host_ms += step_s * 1e3
-            if sched.window_closes > prev_closes:
-                telemetry.note_boundary(window_host_ms)
-                window_host_ms = 0.0
-                prev_closes = sched.window_closes
-            if results:
-                # at most one outstanding request per robot, so a harvest
-                # never carries duplicate robot ids — batched scatter is safe
-                res_ids = np.fromiter(
-                    (res.robot_id for res in results), np.int64,
-                    count=len(results),
-                )
-                toks = np.stack([res.tokens for res in results])
-                chunks.extend(
-                    (res.robot_id, res.submitted_round, res.tokens)
-                    for res in results
-                )
-                cached[res_ids] = tokenizer.decode_action(toks).reshape(
-                    len(results), chunk_len, n_joints
-                )
-                in_flight_mask[res_ids] = False
-                telemetry.note_completions(res_ids)
-                wait_rounds.extend(
-                    res.completed_round - res.submitted_round for res in results
-                )
-                ms = sample_latency_ms_batch(
-                    channel, chunk_len, net_key, res_ids, n_done[res_ids]
-                )
-                n_done[res_ids] += 1
-                offload_ms.extend(ms)
-                for i, r in enumerate(res_ids):
-                    offload_ms_by_robot[r].append(ms[i])
+            in_flight_mask[res_ids] = False
+            telemetry.note_completions(res_ids)
+            wait_rounds.extend(
+                res.completed_round - res.submitted_round for res in results
+            )
+            ms = sample_latency_ms_batch(
+                channel, chunk_len, net_key, res_ids, n_done[res_ids]
+            )
+            n_done[res_ids] += 1
+            offload_ms.extend(ms)
+            for i, r in enumerate(res_ids):
+                offload_ms_by_robot[r].append(ms[i])
 
     wall_s = clock() - t_start
     pool = sched.pool_stats()

@@ -19,10 +19,10 @@ unpartitioned model — the property ``tests/test_partition.py`` pins.
 action-chunk-out interface, plus modeled channel telemetry per call.
 
 For fleet serving the executor additionally exposes a *batched cloud-suffix*
-mode (``edge_prefill`` / ``edge_step`` / ``suffix_prefill`` /
-``suffix_step``): per-robot edge prefixes feed one ragged batch of cut
-activations into a paged suffix that shares the continuous-batching
-scheduler's KV page pool — see ``runtime/scheduler.py``'s split lane.
+mode (``edge_prefill`` / ``suffix_prefill`` / ``build_fleet_decode``):
+per-robot edge prefixes feed one ragged batch of cut activations into a
+paged suffix that shares the continuous-batching scheduler's KV page pool —
+see ``runtime/scheduler.py``'s split lane.
 """
 
 from __future__ import annotations
@@ -58,11 +58,11 @@ class PartitionExecutor:
     the layer's attention + router, ships the top-k-selected hidden states
     cloudward, the cloud applies the resident expert FFNs
     (``moe_apply_experts`` — the literal scan the fused model runs) and
-    ships the mixture output back.  The serial robot-side path
-    (``edge_prefill`` / ``edge_step``) realizes the hop as separate edge /
-    cloud programs chained through the host; the fused pipelined window
-    keeps the seam structural (same ops, one program) and prices the legs
-    via ``modeled_net_ms`` / ``record_chunk_bytes``, like the cut itself.
+    ships the mixture output back.  The robot-side prompt prefill
+    (``edge_prefill``) realizes the hop as separate edge / cloud programs
+    chained through the host; the fused decode window keeps the seam
+    structural (same ops, one program) and prices the legs via
+    ``modeled_net_ms`` / ``record_chunk_bytes``, like the cut itself.
     """
 
     def __init__(
@@ -99,7 +99,8 @@ class PartitionExecutor:
             )
         self.shipped_bytes = 0.0
         # optional Observability handle (attach_partition sets it): when
-        # present, the serial ping-pong legs record per-cut dispatch times
+        # present, the edge and suffix prefill legs record per-cut dispatch
+        # times
         self.obs = None
         self._gs_fns: Dict[Any, Any] = {}  # host-composed gather/scatter jits
 
@@ -325,9 +326,7 @@ class PartitionExecutor:
         self._suffix_spec = spec
         self._edge_extra = extra
         self._edge_prefill_j = jax.jit(self._edge_prefill_impl)
-        self._edge_step_j = jax.jit(self._edge_step_impl)
         self._suffix_prefill_j = jax.jit(self._suffix_prefill_impl)
-        self._suffix_step_j = jax.jit(self._suffix_step_impl)
 
     def init_layer_pool(self, spec):
         """One attention layer's suffix K/V page pools (+1 trash page each).
@@ -452,41 +451,15 @@ class PartitionExecutor:
         )
         return self._edge_seq_blocks(sp, x, positions, caches)
 
-    def edge_step(self, token: int, caches, length: int):
-        """One robot-side ping-pong leg: embed the sampled token, run the
-        edge prefix -> (cut activation [1,1,D], new edge caches)."""
-
-        if self.expert_offload:
-            run = lambda: self._gs_edge_step(token, caches, length)
-        else:
-            run = lambda: self._edge_step_j(
-                self.split_params,
-                jnp.asarray([[token]], jnp.int32),
-                caches,
-                jnp.asarray(length, jnp.int32),
-            )
-        if self.obs is None:
-            return run()
-        t0 = clock()
-        out = run()
-        self._stamp("edge", "step", t0)
-        return out
-
-    def _edge_step_impl(self, sp, token, caches, length):
-        cfg = self.cfg
-        x = embed_lookup(token, sp["embed"], cfg.d_model, cfg.scale_embeddings)
-        x = x.astype(self.model.dtype)
-        return self._edge_step_blocks(sp, x, caches, length)
-
     # ------------------------------------------------------------------
-    # host-composed gather/scatter legs (serial robot-side path)
+    # host-composed gather/scatter legs (robot-side prompt prefill)
     # ------------------------------------------------------------------
     #
-    # With experts offloaded, the robot-side entry points run as separate
+    # With experts offloaded, the robot-side prompt prefill runs as separate
     # edge / cloud PROGRAMS chained through the host — the deployment shape
     # the planner prices: one edge segment per stretch of resident layers,
     # the cloud expert program (``moe_apply_experts``) between them.  The
-    # whole-edge jits above stay the single-program reference.
+    # whole-edge jit above stays the single-program reference.
 
     def _gs_jit(self, key, make):
         fn = self._gs_fns.get(key)
@@ -495,30 +468,23 @@ class PartitionExecutor:
             self._gs_fns[key] = fn
         return fn
 
-    def _gs_block_calls(self, sp, x, caches, positions=None, length=None):
-        """Run the edge prefix as per-layer host-dispatched programs.
+    def _gs_block_calls(self, sp, x, caches, positions):
+        """Run the edge prefix (full-sequence mode) as per-layer
+        host-dispatched programs.
 
-        ``positions`` selects full-sequence mode, ``length`` decode mode.
         Offloaded layers hop: edge mixer+router program -> cloud expert
         program -> edge residual add, three dispatches with the shipped
         tensors ((h2, combine) up, the mixture output down) crossing the
         host exactly where the channel would sit.
         """
 
-        seq = positions is not None
         new = []
         for j, (spec, p, c) in enumerate(zip(self.edge_specs, sp["edge"], caches)):
             if j in self._offload_set:
-                if seq:
-                    mix = self._gs_jit(("mix_seq", j), lambda spec=spec: (
-                        lambda p, x, pos, c: self.model._block_mix_seq(spec, p, x, pos, c)
-                    ))
-                    x, nc = mix(p, x, positions, c)
-                else:
-                    mix = self._gs_jit(("mix_step", j), lambda spec=spec: (
-                        lambda p, x, c, n: self.model._block_mix_step(spec, p, x, c, n)
-                    ))
-                    x, nc = mix(p, x, c, length)
+                mix = self._gs_jit(("mix_seq", j), lambda spec=spec: (
+                    lambda p, x, pos, c: self.model._block_mix_seq(spec, p, x, pos, c)
+                ))
+                x, nc = mix(p, x, positions, c)
                 pre = self._gs_jit("pre_dispatch", lambda: self.model._moe_pre_dispatch)
                 h2, combine = pre(p, x)
                 # >>> uplink: top-k-selected hidden states + combine weights
@@ -529,16 +495,11 @@ class PartitionExecutor:
                 # <<< downlink: expert-mixture output
                 add = self._gs_jit("residual", lambda: (lambda a, b: a + b))
                 x = add(x, out2)
-            elif seq:
+            else:
                 blk = self._gs_jit(("blk_seq", j), lambda spec=spec: (
                     lambda p, x, pos, c: self.model._block_seq(spec, p, x, pos, c)[:2]
                 ))
                 x, nc = blk(p, x, positions, c)
-            else:
-                blk = self._gs_jit(("blk_step", j), lambda spec=spec: (
-                    lambda p, x, c, n: self.model._block_step(spec, p, x, c, n)
-                ))
-                x, nc = blk(p, x, c, length)
             new.append(nc)
         return x, new
 
@@ -553,19 +514,7 @@ class PartitionExecutor:
         caches = self._init_side_caches(
             self.edge_specs, tokens.shape[0], x.shape[1] + self._edge_extra
         )
-        return self._gs_block_calls(sp, x, caches, positions=positions)
-
-    def _gs_edge_step(self, token, caches, length):
-        sp = self.split_params
-        emb = self._gs_jit("embed_step", lambda: (
-            lambda sp, t: embed_lookup(
-                t, sp["embed"], self.cfg.d_model, self.cfg.scale_embeddings
-            ).astype(self.model.dtype)
-        ))
-        x = emb(sp, jnp.asarray([[token]], jnp.int32))
-        return self._gs_block_calls(
-            sp, x, caches, length=jnp.asarray(length, jnp.int32)
-        )
+        return self._gs_block_calls(sp, x, caches, positions)
 
     def suffix_prefill(self, x, layers, pt_new, row_idx, lens, caps):
         """Cloud-side prefill over a batch of shipped cut activations.
@@ -612,38 +561,6 @@ class PartitionExecutor:
         logits = self.model._logits(sp, x[:, -1:])
         return new_layers, logits[:, -1]
 
-    def suffix_step(self, x, layers, page_table, lens, caps):
-        """One batched cloud-suffix decode step over cut activations.
-
-        ``x`` [B,1,D] stacks every active row's shipped activation (idle
-        rows: zeros — their capacity is 0, so they write the trash page).
-        Returns (logits [B, V], new layers).
-        """
-
-        if self.obs is None:
-            return self._suffix_step_j(
-                self.split_params, jnp.asarray(x), layers, jnp.asarray(page_table),
-                jnp.asarray(lens), jnp.asarray(caps),
-            )
-        t0 = clock()
-        out = self._suffix_step_j(
-            self.split_params, jnp.asarray(x), layers, jnp.asarray(page_table),
-            jnp.asarray(lens), jnp.asarray(caps),
-        )
-        self._stamp("suffix", "step", t0)
-        return out
-
-    def _suffix_step_impl(self, sp, x, layers, page_table, lens, caps):
-        x = x.astype(self.model.dtype)
-        paged = (page_table, caps)
-        new_layers = []
-        for spec, p, c in zip(self.cloud_specs, sp["cloud"], layers):
-            x, nc = self.model._block_step(spec, p, x, c, lens, paged=paged)
-            new_layers.append(nc)
-        x = rms_norm(x, sp["final_norm"], self.cfg.norm_eps)
-        logits = self.model._logits(sp, x)
-        return logits[:, -1], new_layers
-
     # ------------------------------------------------------------------
     # pipelined fleet decode (device-resident split windows)
     # ------------------------------------------------------------------
@@ -664,7 +581,7 @@ class PartitionExecutor:
         the returned fn runs ``n_steps`` (argmax → edge prefix → cloud
         suffix) iterations in a single ``lax.scan`` with no host sync —
         the executor-side realization of the planner's pipelined pricing:
-        instead of the serial per-token host ping-pong (sample on host,
+        instead of a per-token host ping-pong (sample on host,
         ship token, edge step, ship activation, suffix step), every leg is
         one fused device program, so edge compute of token t+1 overlaps
         the suffix of token t under XLA's scheduler and the channel hops

@@ -36,13 +36,11 @@ since the last boundary, at the start of the next admission, before any
 program can read it.  ``scan_rounds=1`` degenerates to the classic
 one-round-per-call loop (dispatch + harvest in the same call).
 
-Split lanes come in two flavours: the **serial** lane ping-pongs every token
-through the host (the deployment-faithful per-robot loop), while the
-default **pipelined** lane runs (argmax → edge prefix → merged suffix) for
-a whole window inside one jitted scan — ascending-cut lanes join a
-progressively concatenated row batch at their cut layer, so shared tail
-layers run once over the combined rows and every lane's suffix KV lives in
-one scheduler-owned pool per model layer (pages are globally unique, so
+A split lane runs (argmax → edge prefix → merged suffix) for a whole
+window inside one jitted scan — ascending-cut lanes join a progressively
+concatenated row batch at their cut layer, so shared tail layers run once
+over the combined rows and every lane's suffix KV lives in one
+scheduler-owned pool per model layer (pages are globally unique, so
 cross-lane batching needs no per-lane pool copies).
 
 Every ``ChunkResult`` carries a pool-utilization snapshot (pages in use /
@@ -341,7 +339,7 @@ class ContinuousBatchingScheduler:
         self._token_floor = tokenizer.action_base
         self._admit_fns = {}
         self._decode_fns = {}
-        # pipelined split serving: shared per-MODEL-layer suffix page pools
+        # split serving: shared per-MODEL-layer suffix page pools
         # and the fused per-(cuts, n_steps) decode fns over them
         self._suffix_pools: Optional[Dict[int, dict]] = None
         self._fleet_fns = {}
@@ -377,7 +375,7 @@ class ContinuousBatchingScheduler:
     # request interface
     # ------------------------------------------------------------------
 
-    def attach_partition(self, executor, rows: int = 2, pipelined: bool = True) -> None:
+    def attach_partition(self, executor, rows: int = 2) -> None:
         """Serve partitioned robots' cloud suffixes in the same rounds.
 
         ``executor`` is a ``PartitionExecutor`` over the same model family;
@@ -388,13 +386,10 @@ class ContinuousBatchingScheduler:
         and robots on different cuts still share decode rounds and the one
         page allocator.
 
-        ``pipelined`` (default) decodes the lane inside one fused jitted
-        scan per window — edge stage of token t+1 overlaps the suffix of
-        token t, and compatible lanes batch their suffixes into one call.
-        ``pipelined=False`` keeps the per-token host ping-pong (the serial
-        reference the pipelined path is tested bit-identical against).
-        Heterogeneous pipelined lanes must share parameter slices — derive
-        siblings with ``executor.with_cut``.
+        The lane decodes inside one fused jitted scan per window — edge
+        stage of token t+1 overlaps the suffix of token t, and compatible
+        lanes batch their suffixes into one call.  Heterogeneous lanes must
+        share parameter slices — derive siblings with ``executor.with_cut``.
 
         Expert-offload executors (``executor.expert_offload`` non-empty)
         register under their ``(cut, offload)`` lane key, so an offload
@@ -407,7 +402,7 @@ class ContinuousBatchingScheduler:
             raise ValueError(f"lane {key} already attached")
         if self.obs is not None and getattr(executor, "obs", None) is None:
             executor.obs = self.obs  # lane spans share the run's registry
-        self._lanes[key] = _SplitLane(self, executor, rows, pipelined)
+        self._lanes[key] = _SplitLane(self, executor, rows)
 
     def _lane_for(self, cut) -> "_SplitLane":
         if not self._lanes:
@@ -831,7 +826,7 @@ class ContinuousBatchingScheduler:
                 self._suffix_pools[layer] = ex.init_layer_pool(self.paged_spec)
 
     def _split_fused_step(self, lanes: List["_SplitLane"], n_steps: int) -> None:
-        """Dispatch one fused jitted decode over every active pipelined lane.
+        """Dispatch one fused jitted decode over every active split lane.
 
         Ascending-cut lanes join a progressively concatenated row batch at
         their cut layer, so the shared tail layers run once over the
@@ -1239,21 +1234,10 @@ class ContinuousBatchingScheduler:
             m = self.obs.metrics
             m.counter("sched.windows").inc()
             m.gauge("sched.queue_depth").set(self.n_pending)
-        done: List[ChunkResult] = []
-        # serial (non-pipelined) lanes ping-pong through the host, so their
-        # window runs to completion at dispatch and rides this call's return
-        for lane in [l for l in self._lanes.values() if l.seqs and not l.pipelined]:
-            for _ in range(rounds):
-                if lane.seqs:
-                    done.extend(lane.step(block))
-        if done and self.obs is not None:
-            # serial lanes complete at dispatch; stamp them with their own
-            # boundary read (they never ride a scan window's harvest)
-            self._obs_complete(done, clock())
         w = _ScanWindow(steps_left=rounds, n_steps=rounds * block)
         if self.obs is not None:
             w.t_open = clock()
-        planes = [l for l in self._lanes.values() if l.seqs and l.pipelined]
+        lanes = [l for l in self._lanes.values() if l.seqs]
         with self._span("sched.dispatch") as span:
             if n_cloud:
                 w.toks, self._logits, self._pcache = self._decode_for(
@@ -1262,22 +1246,22 @@ class ContinuousBatchingScheduler:
                 # pending (disaggregated-prefill) rows decode into the trash
                 # page this window; they are merged — and harvested — later
                 w.seqs = [s for s in self._seqs.values() if not s.pending]
-            if planes:
-                self._split_fused_step(planes, w.n_steps)
-                for lane in planes:
+            if lanes:
+                self._split_fused_step(lanes, w.n_steps)
+                for lane in lanes:
                     w.lane_seqs[lane.key] = list(lane.seqs.values())
                     w.lane_toks[lane.key] = lane._pending_toks
                     lane._pending_toks = None
             if span:
                 groups = [(w.seqs, self.rows)] if n_cloud else []
-                groups += [(w.lane_seqs[l.key], l.rows) for l in planes]
+                groups += [(w.lane_seqs[l.key], l.rows) for l in lanes]
                 w.row_tokens = _row_tokens(groups, w.n_steps)
                 span.set(rows=sum(r for _, r in groups), steps=w.n_steps)
         self._window = w
         self._window.steps_left -= 1
         if self._window.steps_left <= 0:
-            done.extend(self._close_window())
-        return done
+            return self._close_window()
+        return []
 
     def _close_window(self) -> List[ChunkResult]:
         """Window boundary: the one host sync, then harvest + releases.
@@ -1362,7 +1346,6 @@ class _SplitSeq:
     robot_id: int
     row: int
     remaining: int
-    length: int              # resident suffix tokens (host-tracked)
     pages: List[int]
     request: ChunkRequest
     admitted_round: int
@@ -1375,20 +1358,11 @@ class _SplitSeq:
 class _SplitLane:
     """Batched cloud-suffix decode for partitioned robots.
 
-    Two decode modes share admission, rows and page accounting:
-
-      * **serial** (``pipelined=False``): each round ping-pongs ``block``
-        times through the host — every active robot's edge prefix embeds
-        its last sampled token (per-robot batch-1 step), the cut
-        activations are stacked, and the executor's paged suffix advances
-        them in one jitted call.  Deployment-faithful, and the numeric
-        reference for the fused path.
-      * **pipelined** (default): the lane's edge prefixes are row-batched
-        device caches, and a whole window of (argmax → edge prefix →
-        merged suffix) steps runs inside ONE jitted scan
-        (``PartitionExecutor.build_fleet_decode``) with no host sync —
-        realizing the planner's pipelined ``max(edge, cloud)`` pricing,
-        and batching compatible suffixes across heterogeneous cuts.
+    The lane's edge prefixes are row-batched device caches, and a whole
+    window of (argmax → edge prefix → merged suffix) steps runs inside ONE
+    jitted scan (``PartitionExecutor.build_fleet_decode``) with no host
+    sync — realizing the planner's pipelined ``max(edge, cloud)`` pricing,
+    and batching compatible suffixes across heterogeneous cuts.
 
     Suffix attention KV lives in the SCHEDULER's shared per-model-layer
     pools (``_ensure_suffix_pools``); the lane holds only per-row state:
@@ -1397,8 +1371,7 @@ class _SplitLane:
     is fungible.
     """
 
-    def __init__(self, sched: ContinuousBatchingScheduler, executor, rows: int,
-                 pipelined: bool = True):
+    def __init__(self, sched: ContinuousBatchingScheduler, executor, rows: int):
         from repro.partition.executor import PartitionExecutor
 
         assert isinstance(executor, PartitionExecutor)
@@ -1408,7 +1381,6 @@ class _SplitLane:
         self.expert_offload = getattr(executor, "expert_offload", ())
         self.key = getattr(executor, "lane_key", executor.cut_layer)
         self.rows = rows
-        self.pipelined = pipelined
         self.queue: Deque[ChunkRequest] = deque()
         self.seqs: Dict[int, _SplitSeq] = {}
         self._free_rows: List[int] = list(range(rows))
@@ -1418,7 +1390,7 @@ class _SplitLane:
         # allocated lazily and DROPPED whenever the lane empties — with a
         # frontier of concurrent lanes, an idle cut must not pin row state
         self._state = None       # {model layer idx: per-row recurrent state}
-        self._edge = None        # row-batched edge caches (pipelined mode)
+        self._edge = None        # row-batched edge caches
         self._pt = self._len = self._cap = self._logits = None
         self._pending_logits = None   # device logits of an in-flight window
         self._pending_toks = None
@@ -1439,10 +1411,9 @@ class _SplitLane:
         sched = self.sched
         sched._ensure_suffix_pools(self.ex)
         self._state = self.ex.init_lane_state(sched.paged_spec, self.rows)
-        if self.pipelined:
-            self._edge = self.ex.init_edge_rows(
-                self.rows, sched.prompt_len + sched.total_tokens
-            )
+        self._edge = self.ex.init_edge_rows(
+            self.rows, sched.prompt_len + sched.total_tokens
+        )
         # host-side row bookkeeping shipped into every suffix call
         self._pt = np.zeros((self.rows, sched.pages_per_req), np.int32)
         self._len = np.zeros((self.rows,), np.int32)
@@ -1475,8 +1446,7 @@ class _SplitLane:
         pad = new - old
         if self._pt is not None:
             self._state = self.ex.pad_lane_state(self._state, pad)
-            if self._edge is not None:
-                self._edge = self.ex.pad_edge_rows(self._edge, pad)
+            self._edge = self.ex.pad_edge_rows(self._edge, pad)
             self._pt = np.concatenate(
                 [self._pt, np.zeros((pad, self.sched.pages_per_req), np.int32)]
             )
@@ -1520,7 +1490,6 @@ class _SplitLane:
             robot_id=req.robot_id,
             row=row,
             remaining=sched.total_tokens,
-            length=sched.prompt_len,
             pages=pages,
             request=req,
             admitted_round=sched.round,
@@ -1531,9 +1500,9 @@ class _SplitLane:
         return seq
 
     def _layers_view(self) -> list:
-        """Assemble the executor's per-cloud-layer list fresh for a serial
-        call: attention layers read the scheduler's SHARED pools, the rest
-        this lane's per-row state."""
+        """Assemble the executor's per-cloud-layer list fresh for the
+        suffix prefill: attention layers read the scheduler's SHARED pools,
+        the rest this lane's per-row state."""
 
         pools = self.sched._suffix_pools
         out = []
@@ -1581,72 +1550,19 @@ class _SplitLane:
         for i, seq in enumerate(new):
             self._logits[seq.row] = logits_new[i]
             del seq._x_cut
-        if self.pipelined:
-            # the robots' batch-1 edge prefill caches become rows of the
-            # lane's device-resident edge state (full-row overwrite, so a
-            # recycled row carries no stale KV)
-            self._edge = self.ex.merge_edge_rows(
-                self._edge,
-                [seq.edge_cache for seq in new],
-                [seq.row for seq in new],
-            )
-            for seq in new:
-                seq.edge_cache = None
-
-    def step(self, block: int) -> List[ChunkResult]:
-        """Serial mode: one round of per-token host ping-pong decode."""
-
-        sched = self.sched
-        done: List[ChunkResult] = []
-        floor = sched._token_floor
-        for _ in range(block):
-            active = [s for s in self.seqs.values() if s.remaining > 0]
-            if not active:
-                break
-            xs = np.zeros(
-                (self.rows, 1, self.ex.cfg.d_model), np.float32
-            )
-            for seq in active:
-                ls = self._logits[seq.row].copy()
-                ls[:floor] = -1e9
-                tok = int(np.argmax(ls))
-                seq.tokens.append(tok)
-                seq.remaining -= 1
-                # ping-pong: the sampled token ships edge-ward, the edge
-                # prefix embeds + runs it, the cut activation ships back
-                x_cut, seq.edge_cache = self.ex.edge_step(
-                    tok, seq.edge_cache, seq.length
-                )
-                xs[seq.row] = np.asarray(x_cut[:, 0], np.float32)
-                seq.length += 1
-            logits, layers = self.ex.suffix_step(
-                xs, self._layers_view(), self._pt, self._len, self._cap
-            )
-            self._writeback(layers)
-            logits = np.asarray(logits, np.float32)
-            for seq in active:
-                self._logits[seq.row] = logits[seq.row]
-            self._len[[s.row for s in active]] += 1
-            for seq in list(active):
-                if seq.remaining == 0:
-                    self.release(seq)
-                    done.append(ChunkResult(
-                        robot_id=seq.robot_id,
-                        tokens=np.asarray(seq.tokens, np.int64),
-                        submitted_round=seq.request.submitted_round,
-                        admitted_round=seq.admitted_round,
-                        completed_round=sched.round,
-                        kind="split",
-                        pool=sched.pool_stats(),
-                        cut=self.cut,
-                        expert_offload=self.expert_offload,
-                        submitted_ts=seq.request.submit_ts,
-                        admitted_ts=seq.admit_ts,
-                    ))
-        return done
+        # the robots' batch-1 edge prefill caches become rows of the lane's
+        # device-resident edge state (full-row overwrite, so a recycled row
+        # carries no stale KV)
+        self._edge = self.ex.merge_edge_rows(
+            self._edge,
+            [seq.edge_cache for seq in new],
+            [seq.row for seq in new],
+        )
+        for seq in new:
+            seq.edge_cache = None
 
     def sync(self, toks) -> np.ndarray:
-        """Pipelined mode, window boundary: read the fused scan's logits
+        """Window boundary: read the fused scan's logits
         and tokens back to the host.  The logits are a writable copy: the
         next ``flush`` writes admitted rows into them."""
 
@@ -1656,7 +1572,7 @@ class _SplitLane:
 
     def harvest(self, seqs: List[_SplitSeq], toks: np.ndarray,
                 completed_round: int) -> List[ChunkResult]:
-        """Pipelined mode, after ``sync``: take each live sequence's
+        """After ``sync``: take each live sequence's
         tokens (over-decoded tail discarded), release completions and dead
         (mid-window-cancelled) rows."""
 
@@ -1670,7 +1586,6 @@ class _SplitLane:
             take = min(seq.remaining, n_steps)
             seq.tokens.extend(int(t) for t in toks[seq.row, :take])
             seq.remaining -= take
-            seq.length += take
             if seq.remaining == 0:
                 self.release(seq)
                 done.append(ChunkResult(

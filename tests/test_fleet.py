@@ -1,5 +1,7 @@
-"""Fleet-scale serving tests: vectorized tick parity, batch scheduler
-entry points, trace-driven arrivals, and episode churn."""
+"""Fleet-scale serving tests: served chunks against the isolated policies,
+batch scheduler entry points, trace-driven arrivals, and episode churn."""
+
+import functools
 
 import jax
 import numpy as np
@@ -30,77 +32,87 @@ def stack():
     return cfg, model, params, tok
 
 
-def _assert_run_parity(a, b):
-    """Bit-for-bit equality of everything the serving loop produces."""
+@functools.lru_cache(maxsize=None)
+def _f32_stack(arch):
+    cfg = get_smoke_config(arch).replace(dtype="float32", param_dtype="float32")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tok = EpisodeTokenizer(cfg.vocab_size)
+    return model, params, tok
 
-    np.testing.assert_array_equal(a["actions"], b["actions"])
-    assert a["offload_ms_by_robot"] == b["offload_ms_by_robot"]
-    assert a["offload_ms"] == b["offload_ms"]
-    assert a["service_rounds"] == b["service_rounds"]
-    assert (a["offloads"] == b["offloads"]).all()
-    ta, tb = a["telemetry"], b["telemetry"]
-    for f in ("fires", "replays", "preempts", "cancels", "completions"):
-        np.testing.assert_array_equal(
-            getattr(ta, f), getattr(tb, f), err_msg=f
+
+# ---------------------------------------------------------------------------
+# every served chunk == the isolated policy on the robot's observation
+# ---------------------------------------------------------------------------
+
+# case -> (arch, serve_fleet kwargs, robot_cuts)
+CHUNK_CASES = {
+    "always": (
+        "openvla-7b",
+        dict(n_robots=6, max_steps=80, max_slots=4, seed=3, scan_rounds=2),
+        None,
+    ),
+    "rapid": (
+        "openvla-7b",
+        dict(n_robots=6, max_steps=80, max_slots=4, seed=3, scan_rounds=2,
+             trigger="rapid", defer_hot_admission=0.2),
+        None,
+    ),
+    "mixed_cuts": (
+        "openvla-7b",
+        dict(n_robots=4, max_steps=60, max_slots=2, scan_rounds=4),
+        {1: 1, 2: 2, 3: 1},
+    ),
+    "expert_lane": (
+        "qwen3-moe-235b-a22b",
+        dict(n_robots=4, max_steps=24),
+        {1: 1, 3: (2, (0,))},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_serve_fleet_chunks_match_isolated_policy(case):
+    """Every chunk the fleet harvests equals the isolated batch-1 policy
+    (``CloudPolicy``, or ``PartitionedPolicy`` at the robot's lane key) on
+    that robot's episode observation at the tick it was submitted (f32)."""
+
+    from repro.launch.serve import CloudPolicy
+    from repro.partition.executor import PartitionExecutor, PartitionedPolicy
+    from repro.robotics.episodes import generate_episode
+
+    arch, kw, robot_cuts = CHUNK_CASES[case]
+    model, params, tok = _f32_stack(arch)
+    ex = None
+    if robot_cuts:
+        ex = PartitionExecutor(model, params, cut_layer=1)
+        kw = dict(kw, partition_executor=ex, robot_cuts=robot_cuts)
+    out = serve_fleet(model, params, tok, verbose=False, **kw)
+
+    # the episodes serve_fleet builds, robot by robot
+    tasks = ["pick_place", "drawer_open", "peg_insertion"]
+    seed = kw.get("seed", 0)
+    eps = [
+        generate_episode(tasks[r % len(tasks)], seed=seed + r)
+        for r in range(kw["n_robots"])
+    ]
+    policies = {None: CloudPolicy(model, params, tok)}
+    for key in set((robot_cuts or {}).values()):
+        cut, off = key if isinstance(key, tuple) else (key, ())
+        policies[key] = PartitionedPolicy(
+            ex.with_cut(cut, expert_offload=off), tok
         )
-    assert ta.ticks == tb.ticks
-    assert a["scan_windows"] == b["scan_windows"]
-    assert a["decode_rounds"] == b["decode_rounds"]
-    assert a["cancelled"] == b["cancelled"]
-    assert a["deferred"] == b["deferred"]
-    if ta.record_streams:
-        sa, sb = ta.streams(), tb.streams()
-        for k in sa:
-            np.testing.assert_array_equal(sa[k], sb[k], err_msg=k)
-
-
-# ---------------------------------------------------------------------------
-# vectorized fleet tick == legacy per-robot loop, bit for bit
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("trigger", ["always", "rapid"])
-def test_vectorized_tick_matches_legacy(stack, trigger):
-    """The array-at-a-time tick reproduces the per-robot loop exactly:
-    actions, telemetry counters, decision streams, latency draws, and
-    scheduler accounting, in both trigger modes."""
-
-    _, model, params, tok = stack
-    kw = dict(
-        n_robots=6, max_steps=140, max_slots=4, seed=3, trigger=trigger,
-        record_streams=True, scan_rounds=2, verbose=False,
-        defer_hot_admission=0.2 if trigger == "rapid" else None,
-    )
-    legacy = serve_fleet(model, params, tok, tick="legacy", **kw)
-    vec = serve_fleet(model, params, tok, tick="vectorized", **kw)
-    _assert_run_parity(legacy, vec)
-    assert legacy["offloads"].sum() > 0
-
-
-def test_vectorized_tick_matches_legacy_mixed_cuts(stack):
-    """Parity holds for a heterogeneous-cut fleet (two lanes + cloud-only
-    robots) under device-resident scan windows (scan_rounds=4)."""
-
-    from repro.partition.executor import PartitionExecutor
-
-    _, model, params, tok = stack
-    ex = PartitionExecutor(model, params, cut_layer=1)
-    kw = dict(
-        n_robots=4, max_steps=60, max_slots=2, partition_executor=ex,
-        robot_cuts={1: 1, 2: 2, 3: 1}, scan_rounds=4, record_streams=True,
-        verbose=False,
-    )
-    legacy = serve_fleet(model, params, tok, tick="legacy", **kw)
-    vec = serve_fleet(model, params, tok, tick="vectorized", **kw)
-    _assert_run_parity(legacy, vec)
-    assert vec["hetero_rounds"] > 0
-    assert vec["active_cuts"] == [1, 2]
-
-
-def test_serve_fleet_rejects_unknown_tick(stack):
-    _, model, params, tok = stack
-    with pytest.raises(ValueError, match="tick"):
-        serve_fleet(model, params, tok, tick="turbo", verbose=False)
+    assert out["chunks"], "no chunk was served"
+    split_served = set()
+    for r, t, tokens in out["chunks"]:
+        key = (robot_cuts or {}).get(r)
+        split_served.add(key)
+        want = policies[key](eps[r].qd[t][None], eps[r].tau[t][None])[0]
+        got = tok.decode_action(tokens).reshape(8, 7)
+        np.testing.assert_array_equal(
+            want, got, err_msg=f"robot {r} tick {t} lane {key}"
+        )
+    assert split_served == set(policies), "a lane served no chunk"
 
 
 # ---------------------------------------------------------------------------

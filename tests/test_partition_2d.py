@@ -396,24 +396,6 @@ def test_fleet_mixed_expert_and_plain_lanes(scan_rounds):
     assert out["pool"].pages_in_use == 0
 
 
-def test_fleet_mixed_lanes_legacy_tick_parity():
-    """The legacy per-robot tick routes tuple lane keys identically."""
-
-    from repro.launch.serve import serve_fleet
-
-    cfg, model, params = _f32_stack("qwen3-moe-235b-a22b")
-    tok = EpisodeTokenizer(cfg.vocab_size)
-    ex = PartitionExecutor(model, params, 1)
-    kw = dict(
-        n_robots=4, max_steps=24, partition_executor=ex,
-        robot_cuts={1: 1, 3: (2, (0,))}, verbose=False,
-    )
-    vec = serve_fleet(model, params, tok, tick="vectorized", **kw)
-    leg = serve_fleet(model, params, tok, tick="legacy", **kw)
-    np.testing.assert_array_equal(vec["actions"], leg["actions"])
-    assert leg["active_cuts"] == vec["active_cuts"]
-
-
 def test_plan_expert_lane_builds_offload_sibling():
     from repro.launch.serve import plan_expert_lane, plan_fleet_partition
 
