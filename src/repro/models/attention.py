@@ -508,9 +508,18 @@ def attention_decode_step_paged(
     maxp = page_table.shape[1]
     pos_b = jnp.broadcast_to(jnp.atleast_1d(cache_len), (b,)).astype(jnp.int32)
     cap_b = jnp.broadcast_to(jnp.atleast_1d(cap), (b,)).astype(jnp.int32)
-    q = (x @ params["wq"].astype(x.dtype)).reshape(b, 1, nh, hd)
-    k = (x @ params["wk"].astype(x.dtype)).reshape(b, 1, nkv, hd)
-    v = (x @ params["wv"].astype(x.dtype)).reshape(b, 1, nkv, hd)
+    # the projections stay 2-D up to a layout barrier: reshaped to heads
+    # first, the heads' layout passes back through each dot, which then
+    # reads a transposed copy of the layer's weight slice made at every
+    # step; behind the barrier each dot streams its slice from the stacked
+    # weight, as the FFN's dots do
+    h = x[:, 0]
+    q, k, v = jax.lax.optimization_barrier(tuple(
+        h @ params[w].astype(x.dtype) for w in ("wq", "wk", "wv")
+    ))
+    q = q.reshape(b, 1, nh, hd)
+    k = k.reshape(b, 1, nkv, hd)
+    v = v.reshape(b, 1, nkv, hd)
     q = rope(q, pos_b[:, None], cfg.rope_theta)
     k = rope(k, pos_b[:, None], cfg.rope_theta)
 

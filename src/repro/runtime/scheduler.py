@@ -107,6 +107,31 @@ def zero_caps(cap, mask):
 _zero_caps = donating_jit(zero_caps, donate_argnums=(0,))
 
 
+def decode_window(model, n_steps: int, rounds: int, token_floor: int):
+    """The scan window ``window(params, logits_rows, pcache) -> (tokens
+    [B, rounds * n_steps], logits_rows, pcache)``: ``rounds`` chained
+    ``model.decode_chunk`` calls of ``n_steps`` tokens each in one
+    ``lax.scan``."""
+
+    def window(params, logits_rows, pcache):
+        def body(carry, _):
+            lg, pc = carry
+            toks, lg, pc = model.decode_chunk(
+                params, lg[:, None], pc, n_steps, token_floor
+            )
+            return (lg[:, -1], pc), toks
+
+        (lg, pc), toks = jax.lax.scan(
+            body, (logits_rows, pcache), None, length=rounds
+        )
+        toks = jnp.swapaxes(toks, 0, 1).reshape(
+            logits_rows.shape[0], rounds * n_steps
+        )
+        return toks, lg, pc
+
+    return window
+
+
 def _bucket(n: int) -> int:
     """Smallest power of two >= n (jit-variant quantization)."""
 
@@ -789,23 +814,10 @@ class ContinuousBatchingScheduler:
         key = (n_steps, rounds, self.rows)
         fn = self._decode_fns.get(key)
         if fn is None:
-            def window(params, logits_rows, pcache):
-                def body(carry, _):
-                    lg, pc = carry
-                    toks, lg, pc = self.model.decode_chunk(
-                        params, lg[:, None], pc, n_steps, self._token_floor
-                    )
-                    return (lg[:, -1], pc), toks
-
-                (lg, pc), toks = jax.lax.scan(
-                    body, (logits_rows, pcache), None, length=rounds
-                )
-                toks = jnp.swapaxes(toks, 0, 1).reshape(
-                    logits_rows.shape[0], rounds * n_steps
-                )
-                return toks, lg, pc
-
-            fn = donating_jit(window, donate_argnums=(1, 2))
+            fn = donating_jit(
+                decode_window(self.model, n_steps, rounds, self._token_floor),
+                donate_argnums=(1, 2),
+            )
             self._decode_fns[key] = fn
         return fn
 

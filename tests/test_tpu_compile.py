@@ -12,6 +12,7 @@ must not touch it unless it runs these tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,3 +107,81 @@ def test_paged_decode_attention_sharded_compiles_for_4_chips(topo, arch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text  # the page-sharded pool is gathered per chip
+
+
+# the benchmark's two cells: (arch, layers, rows, pool pages before the trash
+# page).  Each stack is deeper than ``Model.STEP_UNROLL_MAX``, so its layer
+# scan stays rolled and each step slices one layer's weights from the stack
+WINDOW_CELLS = {
+    "phi3v-16l": ("phi-3-vision-4.2b", 16, 96, 480),
+    "danube3-4b": ("h2o-danube-3-4b", 24, 16, 80),
+}
+
+
+def _materialized(hlo: str):
+    """``(name, result type, opcode, operands)`` of every instruction outside
+    a fused computation: what runs as an op of its own on the chip."""
+
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    ops, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*?)\)", line)
+        if inst and comp not in fused:
+            ops.append(inst.groups())
+    return ops
+
+
+@pytest.mark.parametrize("cell", sorted(WINDOW_CELLS))
+def test_decode_window_streams_qkv_weights_in_place(topo, cell):
+    """The scheduler's decode window (4 rounds of 7 steps) at a cell's widths
+    slices each layer's q/k/v weights inside the dots' own fusions: no op
+    of its own copies a layer's slice at every step.  At phi3v-16l's widths
+    no stacked ``wq``/``wk``/``wv`` is relaid out at entry either."""
+
+    from repro.configs import get_config
+    from repro.models.model import Model
+    from repro.runtime.kv_cache import PagedSpec
+    from repro.runtime.scheduler import decode_window
+
+    arch, layers, rows, pages = WINDOW_CELLS[cell]
+    cfg = get_config(arch).replace(num_layers=layers, sliding_window=0)
+    model = Model(cfg)
+    assert model.repeats > Model.STEP_UNROLL_MAX
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    params = placed(model.abstract_params())
+    spec = PagedSpec(num_pages=pages, page_size=PAGE, max_pages_per_seq=5)
+    pcache = placed(jax.eval_shape(lambda: model.init_paged_cache(rows, spec)))
+    logits = jax.eval_shape(
+        lambda p: model.prefill(p, {"tokens": jnp.zeros((1, 14), jnp.int32)}, extra=0)[0],
+        params,
+    )
+    logits = jax.ShapeDtypeStruct((rows, logits.shape[-1]), logits.dtype, sharding=one_chip)
+    window = decode_window(model, 7, 4, 100)
+    hlo = jax.jit(window, donate_argnums=(1, 2)).lower(params, logits, pcache).compile().as_text()
+    ops = _materialized(hlo)
+
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    one_layer = {f"bf16[1,{d},{n * hd}]" for n in (cfg.num_heads, cfg.num_kv_heads)}
+    assert [(name, op) for name, ty, op, _ in ops if ty.split("{")[0] in one_layer] == []
+
+    # at danube3's widths two entry copies of the stacked wk/wv remain
+    # (their device layout keeps the 3840 dim minor), once per window
+    if cell == "phi3v-16l":
+        qkv = set(re.findall(
+            r"%([\w.\-]+) = \S+ parameter\(\d+\).*attn\\?'\]\[\\?'w[qkv]\\?'\]", hlo
+        ))
+        assert len(qkv) == 3
+        assert [
+            (name, op) for name, _, op, args in ops
+            if op != "tuple" and qkv & set(re.findall(r"%([\w.\-]+)", args))
+        ] == []
